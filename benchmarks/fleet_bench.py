@@ -41,9 +41,9 @@ of stealing.  Validation pins the lease p99 against steal-only and the
 zero-stall contract (no reconfig stall is ever attributable to a
 lease grant).
 
-All runs replay byte-identical traces (same seed) and share one compiled
-decode, so differences are purely scheduling.  Results (slot-step
-efficiency, p50/p95/p99 request latency, throughput, churn, utilization,
+All runs replay byte-identical traces (same seed) and share the
+process-wide compiled decode, so differences are purely scheduling.
+Results (slot-step efficiency, p50/p95/p99 request latency, throughput, churn, utilization,
 the Fig 20 per-feature ablation of the serve predictor) go to
 ``BENCH_fleet.json`` at the repo root.
 
@@ -60,7 +60,7 @@ ROOT = os.path.join(os.path.dirname(__file__), "..")
 OUT = os.path.join(ROOT, "BENCH_fleet.json")
 
 
-def composition_sweep(cfg, params, rt, decode, *, groups: int,
+def composition_sweep(cfg, params, rt, *, groups: int,
                       capacity: int, horizon: int, seed: int) -> Dict:
     """Equal-ways ladders vs the heterogeneous composition lattice.
 
@@ -82,7 +82,7 @@ def composition_sweep(cfg, params, rt, decode, *, groups: int,
     for label, amoeba in variants.items():
         trace = skewed_longtail_trace(horizon=horizon,
                                       vocab_size=cfg.vocab_size, seed=seed)
-        eng = FleetEngine(cfg, params, rt=rt, decode_fn=decode,
+        eng = FleetEngine(cfg, params, rt=rt,
                           fleet=FleetConfig(
                               num_groups=groups, capacity=capacity,
                               router="length_aware", mode="dynamic",
@@ -117,7 +117,7 @@ def composition_sweep(cfg, params, rt, decode, *, groups: int,
     return out
 
 
-def work_stealing_sweep(cfg, params, rt, decode, *, groups: int,
+def work_stealing_sweep(cfg, params, rt, *, groups: int,
                         capacity: int, horizon: int, seed: int,
                         trace_out: str = None) -> Dict:
     """Cross-group work stealing on a shard-skewed trace, on vs off.
@@ -143,7 +143,7 @@ def work_stealing_sweep(cfg, params, rt, decode, *, groups: int,
         # path was requested (repro.obs) — steals/reconfigs/decisions
         # land in the exported JSONL the CI round-trip check consumes
         obs_mode = "full" if trace_out and label == "stealing" else "off"
-        eng = FleetEngine(cfg, params, rt=rt, decode_fn=decode,
+        eng = FleetEngine(cfg, params, rt=rt,
                           fleet=FleetConfig(
                               num_groups=groups, capacity=capacity,
                               router="sticky", mode="dynamic",
@@ -182,7 +182,7 @@ def work_stealing_sweep(cfg, params, rt, decode, *, groups: int,
     return out
 
 
-def slack_lease_sweep(cfg, params, rt, decode, *, groups: int,
+def slack_lease_sweep(cfg, params, rt, *, groups: int,
                       capacity: int, horizon: int, seed: int) -> Dict:
     """Slack leases vs stealing vs re-cutting on a transient burst.
 
@@ -222,7 +222,7 @@ def slack_lease_sweep(cfg, params, rt, decode, *, groups: int,
                                       vocab_size=cfg.vocab_size,
                                       seed=seed, shards=groups,
                                       burst_len=burst_len)
-        eng = FleetEngine(cfg, params, rt=rt, decode_fn=decode,
+        eng = FleetEngine(cfg, params, rt=rt,
                           fleet=FleetConfig(
                               num_groups=groups, capacity=capacity,
                               router="sticky", mode="dynamic",
@@ -270,7 +270,7 @@ def slack_lease_sweep(cfg, params, rt, decode, *, groups: int,
     return out
 
 
-def cluster_hierarchy_sweep(cfg, params, rt, decode, *, capacity: int,
+def cluster_hierarchy_sweep(cfg, params, rt, *, capacity: int,
                             horizon: int, seed: int, chips: int = 2,
                             groups_per_chip: int = 2) -> Dict:
     """Hierarchical vs distance-blind control on a 2D chip mesh.
@@ -318,7 +318,7 @@ def cluster_hierarchy_sweep(cfg, params, rt, decode, *, capacity: int,
         trace = multichip_imbalanced_trace(
             horizon=horizon, vocab_size=cfg.vocab_size, seed=seed,
             chips=chips, groups_per_chip=groups_per_chip)
-        eng = ClusterEngine(cfg, params, rt=rt, decode_fn=decode,
+        eng = ClusterEngine(cfg, params, rt=rt,
                             fleet=FleetConfig(
                                 num_groups=groups, capacity=capacity,
                                 router="sticky", mode="dynamic",
@@ -382,7 +382,6 @@ def fleet_bench(groups: int = 4, capacity: int = 8, horizon: int = 120,
     from repro.fleet import (bursty_longtail_trace, replay_modes,
                              replay_policies)
     from repro.models import transformer as T
-    from repro.serve.engine import make_decode_fn
 
     cfg = get_config("qwen3-14b", reduced=True)
     params, _ = T.init_model(jax.random.PRNGKey(0), cfg)
@@ -437,28 +436,27 @@ def fleet_bench(groups: int = 4, capacity: int = 8, horizon: int = 120,
     # memory"); each sweep recompiles what it needs
     jax.clear_caches()
     print("\n== composition sweep (heterogeneous vs equal ladders) ==")
-    decode = make_decode_fn(cfg, rt)
     out["composition_sweep"] = composition_sweep(
-        cfg, params, rt, decode, groups=groups,
+        cfg, params, rt, groups=groups,
         capacity=capacity, horizon=horizon, seed=seed)
 
     jax.clear_caches()
     print("\n== work-stealing sweep (imbalanced trace, sticky routing) ==")
     out["work_stealing"] = work_stealing_sweep(
-        cfg, params, rt, decode, groups=groups,
+        cfg, params, rt, groups=groups,
         capacity=capacity, horizon=horizon, seed=seed,
         trace_out=trace_out)
 
     jax.clear_caches()
     print("\n== slack lease sweep (transient bursts, sticky routing) ==")
     out["slack_lease"] = slack_lease_sweep(
-        cfg, params, rt, decode, groups=groups,
+        cfg, params, rt, groups=groups,
         capacity=capacity, horizon=horizon, seed=seed)
 
     jax.clear_caches()
     print("\n== cluster hierarchy sweep (2D mesh, tiered links) ==")
     out["cluster_hierarchy"] = cluster_hierarchy_sweep(
-        cfg, params, rt, decode, capacity=capacity,
+        cfg, params, rt, capacity=capacity,
         horizon=horizon, seed=seed)
 
     jax.clear_caches()
@@ -476,7 +474,7 @@ def fleet_bench(groups: int = 4, capacity: int = 8, horizon: int = 120,
                                        write_timing_sidecar)
     out["fleet_scale"] = fleet_scale_sweep(
         cfg, params, rt, groups=scale_groups, capacity=capacity,
-        n_requests=scale_requests, seed=seed, decode=decode)
+        n_requests=scale_requests, seed=seed)
     out["fleet_scale"]["suggest_split_microbench"] = \
         suggest_split_microbench()
     write_timing_sidecar(out["fleet_scale"])

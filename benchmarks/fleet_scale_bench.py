@@ -68,8 +68,7 @@ def fleet_scale_sweep(cfg, params, rt, *, groups: int = 100,
                       obj_measure_ticks: int = 20,
                       seed: int = 0,
                       budget_s: Optional[float] = None,
-                      min_speedup: Optional[float] = None,
-                      decode=None) -> Dict:
+                      min_speedup: Optional[float] = None) -> Dict:
     """Vec-engine variants over the full trace + object steady-state tps."""
     from repro.configs.base import AmoebaConfig, FleetConfig
     from repro.fleet import FleetEngine
@@ -109,7 +108,7 @@ def fleet_scale_sweep(cfg, params, rt, *, groups: int = 100,
 
     # object-engine baseline: identical dynamic config, steady-state
     # segment only (the warmup run absorbs the jit compiles)
-    eng = FleetEngine(cfg, params, rt=rt, decode_fn=decode,
+    eng = FleetEngine(cfg, params, rt=rt,
                       fleet=FleetConfig(
                           num_groups=groups, capacity=capacity, window=64,
                           amoeba=amoeba, engine="object",

@@ -17,6 +17,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from benchmarks import figures, fleet_bench, mesh_amoeba, roofline  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "experiments",
                    "bench_results.json")
@@ -37,6 +38,7 @@ BENCHES = {
 
 
 def main() -> None:
+    enable_compile_cache()
     wanted = sys.argv[1:] or list(BENCHES)
     results = {}
     for name in wanted:

@@ -95,12 +95,10 @@ def main() -> None:
     from repro.configs.base import AmoebaConfig, FleetConfig
     from repro.fleet import FleetEngine, skewed_longtail_trace
     from repro.models import transformer as T
-    from repro.serve.engine import make_decode_fn
 
     cfg = get_config(args.arch, reduced=True)
     params, _ = T.init_model(jax.random.PRNGKey(0), cfg)
     rt = T.Runtime(production=False, remat=False)
-    decode = make_decode_fn(cfg, rt)
     base = AmoebaConfig(split_threshold=0.3, fuse_threshold=0.05,
                         min_phase_steps=2, policy="oracle",
                         max_ways=min(args.capacity, 8))
@@ -108,7 +106,7 @@ def main() -> None:
         trace = skewed_longtail_trace(horizon=args.horizon,
                                       vocab_size=cfg.vocab_size,
                                       seed=args.seed)
-        eng = FleetEngine(cfg, params, rt=rt, decode_fn=decode,
+        eng = FleetEngine(cfg, params, rt=rt,
                           fleet=FleetConfig(
                               num_groups=args.groups,
                               capacity=args.capacity,

@@ -26,6 +26,8 @@ def main() -> None:
     ap.add_argument("--arch", default="qwen3-14b")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.configs import get_config
     from repro.configs.base import AmoebaConfig
     from repro.fleet import bursty_longtail_trace, replay_modes

@@ -1,6 +1,6 @@
 """qwen3-14b — dense GQA with QK-norm.
 
-[hf:Qwen/Qwen3-8B; hf]  40L d_model=5120 40H (GQA kv=8) d_ff=17408
+[hf:Qwen/Qwen3-14B; hf]  40L d_model=5120 40H (GQA kv=8) d_ff=17408
 vocab=151936, qk_norm.
 """
 from repro.configs.base import ModelConfig

@@ -127,8 +127,6 @@ def profile_from_compiled(name: str, lowered, compiled, *, chips: int,
     """
     from repro.core import hlo_analysis
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):       # older jax returns [dict]
-        cost = cost[0]
     try:
         hlo = compiled.as_text()
     except Exception:

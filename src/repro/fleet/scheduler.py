@@ -243,10 +243,10 @@ class FleetEngine:
         self.params = params
         self.rt = rt
         self.fleet = fleet
-        # one compiled decode shared by every group (per batch shape);
-        # callers comparing several fleets can pass one in to share it
-        # wider.  The vec engine never decodes tokens, so it skips the
-        # jit entirely (and tolerates params=None).
+        # every group calls one decode function (its compiled programs,
+        # one per batch shape, are shared process-wide; see
+        # serve.engine.jit_decode).  The vec engine never decodes tokens,
+        # so it skips the jit entirely (and tolerates params=None).
         self._vec = VecState(fleet.num_groups, fleet.capacity) \
             if fleet.engine == "vec" else None
         self._decode = decode_fn if self._vec is not None \
@@ -517,17 +517,16 @@ def replay_modes(model_cfg: ModelConfig, params, rt: T.Runtime,
 
     ``trace_factory`` must return a *fresh* trace per call (replaying
     mutates the requests); same factory + same seed = byte-identical
-    load for every mode.  One compiled decode is shared across modes so
+    load for every mode.  Every mode runs the same compiled decode, so
     differences are purely scheduling.  Used by both the fleet benchmark
     and the demo — raises if any mode fails to drain its trace.
     """
     from repro.configs.base import AmoebaConfig
     amoeba = amoeba or AmoebaConfig()
-    decode = make_decode_fn(model_cfg, rt)
     out: Dict[str, Dict] = {}
     for label, mode, router in modes:
         trace = trace_factory()
-        eng = FleetEngine(model_cfg, params, rt=rt, decode_fn=decode,
+        eng = FleetEngine(model_cfg, params, rt=rt,
                           fleet=FleetConfig(
                               num_groups=groups, capacity=capacity,
                               router=router, mode=mode, window=window,
@@ -575,12 +574,11 @@ def replay_policies(model_cfg: ModelConfig, params, rt: T.Runtime,
                                          label_margin=amoeba.label_margin,
                                          regroup_policy=amoeba.regroup_policy,
                                          hetero=amoeba.hetero)
-    decode = make_decode_fn(model_cfg, rt)
     out: Dict[str, Dict] = {}
     for name in policies:
         trace = trace_factory()
         eng = FleetEngine(
-            model_cfg, params, rt=rt, decode_fn=decode, model=model,
+            model_cfg, params, rt=rt, model=model,
             fleet=FleetConfig(num_groups=groups, capacity=capacity,
                               router=router, mode="dynamic", window=window,
                               amoeba=amoeba.replace(policy=name)))

@@ -17,19 +17,27 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
 
 from repro.core.fusion import MeshPlan
+
+
+def _auto_mesh(shape, axes):
+    # jax.make_mesh defaults to Explicit axes, under which
+    # with_sharding_constraint (shardctx.hint) is an assertion instead of
+    # a hint; the model code is written for GSPMD-propagated Auto axes.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_plan_mesh(plan: MeshPlan):
     """Mesh for a named AMOEBA plan over the same chips."""
-    return jax.make_mesh(plan.shape, plan.axes)
+    return _auto_mesh(plan.shape, plan.axes)
 
 
 def single_pod_plan(name: str = "base") -> MeshPlan:

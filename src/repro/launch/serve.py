@@ -15,6 +15,7 @@ import json
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import AmoebaConfig
 from repro.models import transformer as T
@@ -39,6 +40,7 @@ def main() -> None:
     ap.add_argument("--capacity", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=True)
     params, _ = T.init_model(jax.random.PRNGKey(0), cfg)

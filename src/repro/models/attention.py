@@ -361,7 +361,7 @@ def decode_attention(params, cache: KVCache, x_new: jnp.ndarray,
         cache_spec = KVCache(k=P(bat, "model"), v=P(bat, "model"),
                              k_scale=P(bat, "model") if quant else None,
                              v_scale=P(bat, "model") if quant else None)
-        out, new_cache = shardctx.shard_map(
+        out, new_cache = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(bat), cache_spec, P(bat), P(bat), P(bat)),
             out_specs=(P(bat), cache_spec),

@@ -246,7 +246,7 @@ def moe_sharded(params, x: jnp.ndarray, cfg: ModelConfig) -> Tuple[jnp.ndarray, 
                                  "data" if has_fsdp else None)
 
     aux_spec = MoEAux(aux_loss=P(bat), load=P(bat, None), dropped=P(bat))
-    y, aux = shardctx.shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec_in, P(bat, None)),
         out_specs=(P(bat, None), aux_spec),

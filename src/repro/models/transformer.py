@@ -466,6 +466,46 @@ def logits_fn(params, batch, cfg: ModelConfig, rt: Runtime = DEFAULT_RT):
     return layers.unembed(params["embed"], x, cfg.tie_embeddings), aux
 
 
+def reference_logits(params, tokens: jnp.ndarray, cfg: ModelConfig):
+    """Float32 full-sequence logits, one layer on the device at a time.
+
+    The plain reference for a served model: the same equations as
+    :func:`logits_fn`, on float32 copies of the weights, with float32
+    matmuls at ``"highest"`` precision (a TPU otherwise runs float32
+    matmuls in bf16 passes).  Only one layer's float32 copy exists at a
+    time, so a model whose float32 weights exceed device memory still
+    fits beside its bf16 weights.  Decoder-only stacks; tokens (B, S).
+    """
+    if cfg.encoder_layers or cfg.vision_stub:
+        raise NotImplementedError("reference_logits: decoder-only stacks")
+    cfg32 = cfg.replace(dtype="float32")
+    rt = Runtime(production=False, remat=False)
+    f32 = lambda tree: jax.tree.map(lambda a: a.astype(jnp.float32), tree)
+    pattern = _pattern(cfg)
+
+    @partial(jax.jit, static_argnames="kind")
+    def block(p, x, positions, kind):
+        return block_forward(f32(p), x, positions, None, cfg32, kind, rt)[0]
+
+    @jax.jit
+    def head(p, x):
+        x = layers.rmsnorm(f32(p["final_norm"]), x, cfg.norm_eps)
+        return layers.unembed(f32(p["embed"]), x, cfg.tie_embeddings)
+
+    with jax.default_matmul_precision("highest"):
+        x, positions, _ = embed_inputs(params, {"tokens": tokens}, cfg)
+        x = x.astype(jnp.float32)
+        n_rep = cfg.num_layers // len(pattern) if "reps" in params else 0
+        for r in range(n_rep):
+            for i, kind in enumerate(pattern):
+                p = jax.tree.map(lambda a: a[r], params["reps"][i])
+                x = block(p, x, positions, kind=kind)
+        for j, p in enumerate(params.get("rest", ())):
+            x = block(p, x, positions, kind=pattern[j % len(pattern)])
+        return head({"final_norm": params["final_norm"],
+                     "embed": params["embed"]}, x)
+
+
 # ---------------------------------------------------------------------------
 # Training loss (chunked over sequence, vocab sharded over 'model')
 # ---------------------------------------------------------------------------

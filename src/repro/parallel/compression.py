@@ -57,12 +57,7 @@ def compressed_psum_mean(grads: Any, axis_name: str,
     Must be called inside shard_map with ``axis_name`` mapped.  Returns
     (mean_grads, new_residuals).
     """
-    # jax.lax.axis_size is newer-jax only; psum(1) is the portable spelling
-    # (statically folded under shard_map, no runtime collective)
-    if hasattr(jax.lax, "axis_size"):
-        n = jax.lax.axis_size(axis_name)
-    else:
-        n = jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def one(g, res):
         gf = g.astype(jnp.float32)

@@ -36,43 +36,13 @@ def model_axes() -> Tuple[str, ...]:
     return tuple(a for a in mesh.axis_names if a == "model")
 
 
-def _mesh_scope(mesh: Mesh):
-    """Installed-mesh context across jax versions: ``jax.set_mesh`` (new),
-    ``jax.sharding.use_mesh``/``set_mesh`` (transitional), or the mesh's own
-    context manager (legacy pjit-style ``with mesh:``)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    for name in ("use_mesh", "set_mesh"):
-        fn = getattr(jax.sharding, name, None)
-        if fn is not None:
-            return fn(mesh)
-    return mesh
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes it at top level with a ``check_vma`` kwarg; older
-    releases only have ``jax.experimental.shard_map`` where the same knob
-    is called ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-
-
 @contextlib.contextmanager
 def use_mesh(mesh: Optional[Mesh]):
     prev = current_mesh()
     _state.mesh = mesh
     try:
         if mesh is not None:
-            with _mesh_scope(mesh):
+            with jax.set_mesh(mesh):
                 yield mesh
         else:
             yield None

@@ -36,6 +36,7 @@ cross-request contamination).
 from __future__ import annotations
 
 import collections
+import functools
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence
 
@@ -126,10 +127,20 @@ def _group_done(g: Optional[_Group]) -> bool:
     return g is None or all(r.done for r in g.requests)
 
 
+# One jitted prefill and one jitted decode step for the whole process:
+# compiled programs are keyed on the static config, runtime and KV window
+# and on the batch shape, so every group and engine serving one model
+# shares them (a prefill per wave size and prompt length, a decode step
+# per batch size).
+jit_prefill = jax.jit(T.prefill, static_argnames=("cfg", "rt", "window"))
+jit_decode = jax.jit(T.decode_step, static_argnames=("cfg", "rt"))
+
+
 def make_decode_fn(model_cfg: ModelConfig, rt: T.Runtime) -> Callable:
-    """One jitted ``decode_step`` closure — the single place its jit options
-    live, shared by the N=1 engine, the fleet, and benchmark comparisons."""
-    return jax.jit(lambda p, s, t: T.decode_step(p, s, t, model_cfg, rt))
+    """``decode_step(params, state, tokens)`` for one model and runtime —
+    the single place its jit options live, shared by the N=1 engine, the
+    fleet, and benchmark comparisons."""
+    return functools.partial(jit_decode, cfg=model_cfg, rt=rt)
 
 
 # group step outcomes
@@ -304,8 +315,9 @@ class ReconfigurableGroup:
         states, lasts, ordered = [], [], []
         for plen, reqs in sorted(by_len.items()):
             toks = jnp.asarray([r.prompt for r in reqs], jnp.int32)
-            logits, st = T.prefill(self.params, {"tokens": toks}, self.cfg,
-                                   self.rt, window=self.window)
+            logits, st = jit_prefill(self.params, {"tokens": toks},
+                                     cfg=self.cfg, rt=self.rt,
+                                     window=self.window)
             nxt = jnp.argmax(logits, axis=-1)
             for r, t in zip(reqs, np.asarray(nxt)):
                 r.generated.append(int(t))

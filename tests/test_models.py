@@ -65,6 +65,25 @@ def test_decode_matches_full_forward(arch):
     assert max(errs) < 1e-3, errs
 
 
+@pytest.mark.parametrize("arch", [a for a in ARCH_IDS
+                                  if not get_config(a).encoder_layers
+                                  and not get_config(a).vision_stub])
+def test_reference_logits_matches_full_forward(arch):
+    """The layer-at-a-time float32 reference == logits_fn in float32."""
+    cfg = get_config(arch, reduced=True)
+    params, _ = T.init_model(jax.random.PRNGKey(0), cfg)
+    toks = _batch(cfg, 2, 24)["tokens"]
+    ref = T.reference_logits(params, toks, cfg)
+    assert ref.dtype == jnp.float32 and ref.shape == (2, 24, cfg.vocab_size)
+    cfg32 = cfg.replace(dtype="float32")
+    p32 = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+    with jax.default_matmul_precision("highest"):
+        full, _ = T.logits_fn(p32, {"tokens": toks}, cfg32,
+                              T.Runtime(production=False, remat=False))
+    scale = float(jnp.max(jnp.abs(full)))
+    assert float(jnp.max(jnp.abs(ref - full))) <= 1e-5 * scale
+
+
 @pytest.mark.parametrize("arch", ["qwen3-14b", "recurrentgemma-9b",
                                   "falcon-mamba-7b"])
 def test_pallas_kernel_path_matches_jnp(arch):

@@ -12,7 +12,7 @@ CHILD = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig, TrainConfig
 from repro.models import transformer as T
@@ -20,7 +20,8 @@ from repro.parallel import shardctx, resolve
 from repro.train import Trainer
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 
 # --- MoE: sharded path on a real mesh == dense oracle --------------------
 cfg = get_config("deepseek-moe-16b", reduced=True).replace(dtype="float32")
@@ -73,7 +74,7 @@ g = jax.random.normal(jax.random.PRNGKey(3), (8, 16, 64), jnp.float32)
 def body(gl):
     mean, res = C.compressed_psum_mean({"g": gl}, "data")
     return mean["g"], res["g"]
-mean, res = jax.jit(shardctx.shard_map(
+mean, res = jax.jit(jax.shard_map(
     body, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
     check_vma=False))(g)
 # compare against the true mean over the data axis shards
@@ -84,13 +85,21 @@ err = float(jnp.max(jnp.abs(mean - true)))
 bound = float(jnp.max(jnp.abs(g))) / 127.0 * 1.5
 assert err <= bound, (err, bound)
 print("compression ok", err)
+
+# --- chip_smoke.py's four-chip phase, on 4 of the devices -------------------
+import chip_smoke
+cfg3 = get_config("qwen3-14b", reduced=True)
+res = chip_smoke.sharded_check(cfg3, chip_smoke.init_params(cfg3, 0), 0,
+                               chips=4)
+print("smoke sharded ok", res)
 print("ALL-MULTIDEVICE-OK")
 """
 
 
 def test_multidevice_numerics():
     env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env["PYTHONPATH"] = os.pathsep.join([os.path.join(root, "src"), root])
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", CHILD], env=env,
                        capture_output=True, text=True, timeout=1200)

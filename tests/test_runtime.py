@@ -222,3 +222,24 @@ def test_serve_regroup_beats_fused_on_divergent_load(serve_setup):
         eng.submit(mk())
         effs[name] = eng.run(dynamic=dyn).efficiency
     assert effs["regroup"] > effs["fused"] * 1.1
+
+
+# -- compile cache -------------------------------------------------------------
+
+def test_compile_cache_dir_from_env_else_fixed_in_checkout(monkeypatch,
+                                                            tmp_path):
+    from repro.compile_cache import enable_compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        first = enable_compile_cache()
+        assert enable_compile_cache() == first
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert first == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == first
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
