@@ -43,9 +43,9 @@ lease grant).
 
 All runs replay byte-identical traces (same seed) and share the
 process-wide compiled decode, so differences are purely scheduling.
-Results (slot-step efficiency, p50/p95/p99 request latency, throughput, churn, utilization,
-the Fig 20 per-feature ablation of the serve predictor) go to
-``BENCH_fleet.json`` at the repo root.
+Results (slot-step efficiency, p50/p95/p99 request latency, throughput,
+churn, utilization, the Fig 20 per-feature ablation of the serve
+predictor) go to ``BENCH_fleet.json`` at the repo root.
 
     PYTHONPATH=src python -m benchmarks.run fleet
     PYTHONPATH=src python benchmarks/fleet_bench.py --quick   # CI smoke

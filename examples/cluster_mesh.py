@@ -46,7 +46,6 @@ def main() -> None:
                                     MigrationConfig)
     from repro.fleet import multichip_imbalanced_trace
     from repro.models import transformer as T
-    from repro.serve.engine import make_decode_fn
 
     cfg = get_config(args.arch, reduced=True)
     groups = args.chips * args.groups_per_chip
@@ -87,7 +86,6 @@ def main() -> None:
     print("\n== cluster: one hot chip, tiered links, two cost models ==")
     params, _ = T.init_model(jax.random.PRNGKey(0), cfg)
     rt = T.Runtime(production=False, remat=False)
-    decode = make_decode_fn(cfg, rt)
     amoeba = AmoebaConfig(split_threshold=0.3, fuse_threshold=0.05,
                           min_phase_steps=2)
     for label, cluster in (("flat_blind", ccfg.replace(distance_blind=True)),
@@ -96,7 +94,7 @@ def main() -> None:
             horizon=args.horizon, vocab_size=cfg.vocab_size,
             seed=args.seed, chips=args.chips,
             groups_per_chip=args.groups_per_chip)
-        eng = ClusterEngine(cfg, params, rt=rt, decode_fn=decode,
+        eng = ClusterEngine(cfg, params, rt=rt,
                             fleet=FleetConfig(
                                 num_groups=groups, capacity=args.capacity,
                                 router="sticky", mode="dynamic",

@@ -36,7 +36,6 @@ def main() -> None:
                                     MigrationConfig)
     from repro.fleet import FleetEngine, KVTransferCost, imbalanced_trace
     from repro.models import transformer as T
-    from repro.serve.engine import make_decode_fn
 
     cfg = get_config(args.arch, reduced=True)
 
@@ -55,7 +54,6 @@ def main() -> None:
     print("\n== fleet: sticky routing on a shard-skewed trace ==")
     params, _ = T.init_model(jax.random.PRNGKey(0), cfg)
     rt = T.Runtime(production=False, remat=False)
-    decode = make_decode_fn(cfg, rt)
     amoeba = AmoebaConfig(split_threshold=0.3, fuse_threshold=0.05,
                           min_phase_steps=2)
     for label, mig in (("no_stealing", MigrationConfig(enabled=False)),
@@ -63,7 +61,7 @@ def main() -> None:
         trace = imbalanced_trace(horizon=args.horizon,
                                  vocab_size=cfg.vocab_size,
                                  seed=args.seed, shards=args.groups)
-        eng = FleetEngine(cfg, params, rt=rt, decode_fn=decode,
+        eng = FleetEngine(cfg, params, rt=rt,
                           fleet=FleetConfig(
                               num_groups=args.groups,
                               capacity=args.capacity,

@@ -64,8 +64,7 @@ from repro.fleet.vec import VecGroup, VecState
 from repro.models import transformer as T
 from repro.obs.events import OBS_MODES, EventLog
 from repro.obs.metrics import MetricsRegistry
-from repro.serve.engine import (IDLE, TICKED, ReconfigurableGroup, Request,
-                                make_decode_fn)
+from repro.serve.engine import IDLE, TICKED, ReconfigurableGroup, Request
 
 
 # -- routing policies ----------------------------------------------------------
@@ -221,7 +220,6 @@ class FleetEngine:
     def __init__(self, model_cfg: ModelConfig, params,
                  rt: T.Runtime = T.Runtime(production=False, remat=False),
                  fleet: FleetConfig = FleetConfig(),
-                 decode_fn: Optional[Callable] = None,
                  model: Optional[LogisticModel] = None,
                  policy: Optional[ReconfigPolicy] = None):
         if fleet.num_groups < 1:
@@ -243,14 +241,11 @@ class FleetEngine:
         self.params = params
         self.rt = rt
         self.fleet = fleet
-        # every group calls one decode function (its compiled programs,
-        # one per batch shape, are shared process-wide; see
-        # serve.engine.jit_decode).  The vec engine never decodes tokens,
-        # so it skips the jit entirely (and tolerates params=None).
+        # object groups share the process-wide compiled decode (one program
+        # per batch shape; see serve.engine.jit_decode).  The vec engine
+        # never decodes tokens, so it tolerates params=None.
         self._vec = VecState(fleet.num_groups, fleet.capacity) \
             if fleet.engine == "vec" else None
-        self._decode = decode_fn if self._vec is not None \
-            else (decode_fn or make_decode_fn(model_cfg, rt))
         # chip-wide control plane: one replay buffer and one policy object
         # shared by every group, so online learning pools all samples
         self.telemetry = FleetTelemetry(
@@ -289,8 +284,7 @@ class FleetEngine:
                 for i in range(fleet.num_groups)]
         else:
             self.groups = [
-                ReconfigurableGroup(model_cfg, params, gid=i,
-                                    decode_fn=self._decode, **grp_kw)
+                ReconfigurableGroup(model_cfg, params, gid=i, **grp_kw)
                 for i in range(fleet.num_groups)]
         self._router = ROUTERS[fleet.router]
         self._router_state: Dict = {"long_threshold": fleet.long_threshold,

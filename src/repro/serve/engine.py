@@ -137,9 +137,8 @@ jit_decode = jax.jit(T.decode_step, static_argnames=("cfg", "rt"))
 
 
 def make_decode_fn(model_cfg: ModelConfig, rt: T.Runtime) -> Callable:
-    """``decode_step(params, state, tokens)`` for one model and runtime —
-    the single place its jit options live, shared by the N=1 engine, the
-    fleet, and benchmark comparisons."""
+    """:data:`jit_decode` bound to one model and runtime:
+    ``decode(params, state, tokens)``."""
     return functools.partial(jit_decode, cfg=model_cfg, rt=rt)
 
 

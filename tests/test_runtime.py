@@ -230,10 +230,12 @@ def test_compile_cache_dir_from_env_else_fixed_in_checkout(monkeypatch,
                                                             tmp_path):
     from repro.compile_cache import enable_compile_cache
     prev = jax.config.jax_compilation_cache_dir
+    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert enable_compile_cache() == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
 
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         first = enable_compile_cache()
@@ -243,3 +245,5 @@ def test_compile_cache_dir_from_env_else_fixed_in_checkout(monkeypatch,
         assert jax.config.jax_compilation_cache_dir == first
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          prev_min)
