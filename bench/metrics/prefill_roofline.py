@@ -1,0 +1,21 @@
+"""Prefill: the least time the prefill calls in the trace need (from
+``counts.prefill_call``: the larger of FLOPs over peak and bytes over
+bandwidth, call by call) over their device time, in %."""
+import counts
+from layer import PREFILL_PROGRAM
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    dev = ctx.trace.program_s.get(PREFILL_PROGRAM, 0.0)
+    if dev <= 0:
+        return None
+    least = 0.0
+    for t in ctx.traced():
+        for rows, plen in t.prefill:
+            least += counts.prefill_call(ctx.shape, rows, plen).least_seconds(
+                ctx.peak_flops, ctx.peak_bw)
+    if least <= 0:
+        return None
+    return 100.0 * least / dev
