@@ -1,8 +1,8 @@
 """Benchmark driver: one harness per paper table/figure + the mesh-level
-roofline/AMOEBA analyses.
+AMOEBA analyses.
 
     PYTHONPATH=src python -m benchmarks.run            # everything
-    PYTHONPATH=src python -m benchmarks.run fig12 roofline
+    PYTHONPATH=src python -m benchmarks.run fig12 fleet
 
 Writes machine-readable results to experiments/bench_results.json.
 """
@@ -16,7 +16,7 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from benchmarks import figures, fleet_bench, mesh_amoeba, roofline  # noqa: E402
+from benchmarks import figures, fleet_bench, mesh_amoeba  # noqa: E402
 from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "experiments",
@@ -30,7 +30,6 @@ BENCHES = {
     "fig19": figures.fig19_dynamics,
     "fig20": figures.fig20_predictor,
     "fig21": figures.fig21_dws,
-    "roofline": lambda: {"cells": roofline.main()},
     "mesh_plan_selection": mesh_amoeba.plan_selection,
     "serving_regroup": mesh_amoeba.serving_regroup,
     "fleet": fleet_bench.fleet_bench,

@@ -64,6 +64,7 @@ from repro.fleet.vec import VecGroup, VecState
 from repro.models import transformer as T
 from repro.obs.events import OBS_MODES, EventLog
 from repro.obs.metrics import MetricsRegistry
+from repro.obs.spans import install_gc_spans, span
 from repro.serve.engine import IDLE, TICKED, ReconfigurableGroup, Request
 
 
@@ -236,6 +237,7 @@ class FleetEngine:
         # structured event stream + per-tick metrics (repro.obs); every
         # component below shares this one log so the trace is fleet-wide
         self.obs = EventLog(mode=fleet.obs)
+        install_gc_spans()
         self._metrics = MetricsRegistry() if self.obs.full else None
         self.cfg = model_cfg
         self.params = params
@@ -355,9 +357,11 @@ class FleetEngine:
         a trace can be replayed across engines without aliasing
         surprises.
         """
+        t = time.perf_counter()
         for r in requests:
             if r.arrival < 0:
                 r.arrival = 0
+            r.submitted_s = t
             self.requests.append(r)
             self._seq += 1
             heapq.heappush(self._pending, (r.arrival, self._seq, r))
@@ -406,18 +410,18 @@ class FleetEngine:
             self._vec.decode_tick(self.wall, self.groups)
         return statuses
 
-    def run(self, dynamic: bool = True,
-            max_ticks: int = 1_000_000) -> Dict:
-        """Drive the fleet until the trace is fully drained (or max_ticks)."""
-        t0 = time.perf_counter()
-        while self.wall < max_ticks:
-            if self.obs.enabled:
-                # one clock for every emitter that has no tick in scope
-                # (controller.observe, policy refits, live migrations)
-                self.obs.set_tick(self.wall)
+    def _tick(self, dynamic: bool, max_ticks: int) -> bool:
+        """One iteration of :meth:`run`'s loop; False once the trace is
+        drained."""
+        if self.obs.enabled:
+            # one clock for every emitter that has no tick in scope
+            # (controller.observe, policy refits, live migrations)
+            self.obs.set_tick(self.wall)
+        with span("fleet.deliver"):
             self._deliver()
-            if self.controller is not None and dynamic \
-                    and self.fleet.mode == "dynamic":
+        if self.controller is not None and dynamic \
+                and self.fleet.mode == "dynamic":
+            with span("fleet.rebalance"):
                 if self._vec is not None \
                         and self.wall % self.controller.every == 0:
                     # rebalance ticks read Request.generated lengths
@@ -430,22 +434,24 @@ class FleetEngine:
                     # execute between ticks: steals re-queue, live
                     # migrations splice KV rows before anyone decodes
                     self.planner.execute(plans, self.groups, now=self.wall)
-            statuses = self._step_groups(dynamic)
-            ticked = sum(s == TICKED for s in statuses)
-            if all(s == IDLE for s in statuses):
-                nxt_evt = self._next_event()
-                if nxt_evt is None:
-                    # terminal probe: the trace is drained, not an idle tick
-                    break
-                # fast-forward the idle gap to the next event, never
-                # past the caller's tick bound
-                nxt = min(max(self.wall + 1, nxt_evt), max_ticks)
+        statuses = self._step_groups(dynamic)
+        ticked = sum(s == TICKED for s in statuses)
+        if all(s == IDLE for s in statuses):
+            nxt_evt = self._next_event()
+            if nxt_evt is None:
+                # terminal probe: the trace is drained, not an idle tick
+                return False
+            # fast-forward the idle gap to the next event, never
+            # past the caller's tick bound
+            nxt = min(max(self.wall + 1, nxt_evt), max_ticks)
+            with span("fleet.telemetry"):
                 self.telemetry.on_tick(self.wall, self.groups, 0,
                                        all_idle=True)
                 self.telemetry.on_idle_gap(nxt - self.wall - 1,
                                            len(self.groups))
-                self.wall = nxt
-                continue
+            self.wall = nxt
+            return True
+        with span("fleet.telemetry"):
             self.telemetry.on_tick(self.wall, self.groups, ticked)
             if self._metrics is not None:
                 # vec: one fleet-wide sum instead of a slice per group
@@ -453,26 +459,36 @@ class FleetEngine:
                     if self._vec is not None else None
                 self._metrics.sample_fleet(self.wall, self.groups,
                                            planner=self.planner, live=live)
-            self.wall += 1
-        if self._vec is not None:
-            self._vec.sync_generated()
-        for g in self.groups:
-            g.finalize()
-        self.obs.meta.setdefault("obs_mode", self.obs.mode)
-        self.obs.meta["wall_ticks"] = self.wall
-        summary = self.telemetry.summary(self.groups, self.requests,
-                                         policy=self.policy,
-                                         fleet_controller=self.controller,
-                                         router_state=self._router_state,
-                                         obs=self.obs,
-                                         metrics=self._metrics)
-        # perf trajectory: every summary (and thus every BENCH entry)
-        # carries measured wall seconds and simulated ticks per second;
-        # cumulative across run() calls on the same engine
-        self._run_seconds += time.perf_counter() - t0
-        summary["wall_s"] = round(self._run_seconds, 4)
-        summary["ticks_per_sec"] = round(
-            summary["wall_ticks"] / max(self._run_seconds, 1e-9), 1)
+        self.wall += 1
+        return True
+
+    def run(self, dynamic: bool = True,
+            max_ticks: int = 1_000_000) -> Dict:
+        """Drive the fleet until the trace is fully drained (or max_ticks)."""
+        t0 = time.perf_counter()
+        while self.wall < max_ticks:
+            with span("fleet.tick"):
+                if not self._tick(dynamic, max_ticks):
+                    break
+        with span("fleet.close"):
+            if self._vec is not None:
+                self._vec.sync_generated()
+            for g in self.groups:
+                g.finalize()
+            self.obs.meta.setdefault("obs_mode", self.obs.mode)
+            self.obs.meta["wall_ticks"] = self.wall
+            summary = self.telemetry.summary(
+                self.groups, self.requests, policy=self.policy,
+                fleet_controller=self.controller,
+                router_state=self._router_state, obs=self.obs,
+                metrics=self._metrics)
+            # perf trajectory: every summary (and thus every BENCH entry)
+            # carries measured wall seconds and simulated ticks per second;
+            # cumulative across run() calls on the same engine
+            self._run_seconds += time.perf_counter() - t0
+            summary["wall_s"] = round(self._run_seconds, 4)
+            summary["ticks_per_sec"] = round(
+                summary["wall_ticks"] / max(self._run_seconds, 1e-9), 1)
         return summary
 
     # -- aggregates -------------------------------------------------------------

@@ -8,6 +8,11 @@ and exporters (JSONL + Chrome trace-event for Perfetto).  Select with
 ``FleetConfig.obs`` — ``"off"`` (default, near-zero overhead and
 bit-identical summaries), ``"summary"`` (counters only), or ``"full"``
 (ring buffer + metrics + audit).
+
+Decisions go to the :class:`EventLog`, stamped in ticks; wall time goes to
+spans (:mod:`repro.obs.spans`): named host spans that the JAX profiler
+records beside the device's own planes while a trace is taken, at the cost
+of one inactive ``TraceMe`` each when none is.
 """
 from repro.obs.audit import (decision_rows, misprediction_rate,
                              top_mispredictions, verify_replay)
@@ -19,6 +24,7 @@ from repro.obs.metrics import Histogram, MetricsRegistry
 from repro.obs.report import (attribution_rows, render_attribution,
                               render_mispredictions, render_report,
                               render_timeline)
+from repro.obs.spans import install_gc_spans, span
 
 __all__ = [
     "EVENT_KINDS", "OBS_MODES", "Event", "EventLog", "NULL_LOG", "jsonable",
@@ -28,4 +34,5 @@ __all__ = [
     "write_jsonl", "read_jsonl", "chrome_trace", "write_chrome_trace",
     "attribution_rows", "render_timeline", "render_attribution",
     "render_mispredictions", "render_report",
+    "span", "install_gc_spans",
 ]

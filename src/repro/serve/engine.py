@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence
 
@@ -52,6 +53,7 @@ from repro.control.policies import ReconfigPolicy
 from repro.core.predictor import LogisticModel
 from repro.models import transformer as T
 from repro.obs.events import NULL_LOG, EventLog
+from repro.obs.spans import span
 from repro.serve import state_utils as su
 
 
@@ -70,6 +72,10 @@ class Request:
     # soft preference for one part of the admitting group (set by
     # part-addressable routing and by migration steals); cleared on admit
     part_affinity: Optional[int] = None
+    # host clock (time.perf_counter) at FleetEngine.submit and at the
+    # first admission wave that took the request; never in a summary
+    submitted_s: Optional[float] = field(default=None, compare=False)
+    admitted_s: Optional[float] = field(default=None, compare=False)
 
     @property
     def remaining(self) -> int:
@@ -300,6 +306,11 @@ class ReconfigurableGroup:
             wave.append(r)
         for r in reversed(deferred):
             self.queue.appendleft(r)
+        if wave:
+            t = time.perf_counter()
+            for r in wave:
+                if r.admitted_s is None:
+                    r.admitted_s = t
         return wave
 
     def _prefill_wave(self, n_slots: int, now: int,
@@ -313,20 +324,24 @@ class ReconfigurableGroup:
             by_len[len(r.prompt)].append(r)
         states, lasts, ordered = [], [], []
         for plen, reqs in sorted(by_len.items()):
-            toks = jnp.asarray([r.prompt for r in reqs], jnp.int32)
-            logits, st = jit_prefill(self.params, {"tokens": toks},
-                                     cfg=self.cfg, rt=self.rt,
-                                     window=self.window)
-            nxt = jnp.argmax(logits, axis=-1)
-            for r, t in zip(reqs, np.asarray(nxt)):
-                r.generated.append(int(t))
-                if r.done:
-                    r.finish = now
-            self.stats.prefill_tokens += plen * len(reqs)
-            self.stats.useful_tokens += len(reqs)
-            states.append(st)
-            lasts.append(nxt[:, None].astype(jnp.int32))
-            ordered.extend(reqs)
+            with span("group.prefill", gid=self.gid, part=part_idx):
+                toks = jnp.asarray([r.prompt for r in reqs], jnp.int32)
+                logits, st = jit_prefill(self.params, {"tokens": toks},
+                                         cfg=self.cfg, rt=self.rt,
+                                         window=self.window)
+                nxt = jnp.argmax(logits, axis=-1)
+                with span("group.prefill_sync", gid=self.gid,
+                          part=part_idx):
+                    first = np.asarray(nxt)
+                for r, t in zip(reqs, first):
+                    r.generated.append(int(t))
+                    if r.done:
+                        r.finish = now
+                self.stats.prefill_tokens += plen * len(reqs)
+                self.stats.useful_tokens += len(reqs)
+                states.append(st)
+                lasts.append(nxt[:, None].astype(jnp.int32))
+                ordered.extend(reqs)
         return _Group(ordered, su.concat(states),
                       jnp.concatenate(lasts, axis=0))
 
@@ -338,18 +353,20 @@ class ReconfigurableGroup:
         live = [i for i, r in enumerate(g.requests) if not r.done]
         if not live:
             return
-        logits, new_state = self._decode(self.params, g.state, g.last)
-        nxt = jnp.argmax(logits, axis=-1)
-        arr = np.asarray(nxt)
-        for i, r in enumerate(g.requests):
-            if not r.done:
-                r.generated.append(int(arr[i]))
-                self.stats.useful_tokens += 1
-                if r.done:
-                    r.finish = now
-        g.state = new_state
-        g.last = nxt[:, None].astype(jnp.int32)
-        self.stats.slot_steps += slots
+        with span("group.decode", gid=self.gid, part=part_idx):
+            logits, new_state = self._decode(self.params, g.state, g.last)
+            nxt = jnp.argmax(logits, axis=-1)
+            with span("group.decode_sync", gid=self.gid, part=part_idx):
+                arr = np.asarray(nxt)
+            for i, r in enumerate(g.requests):
+                if not r.done:
+                    r.generated.append(int(arr[i]))
+                    self.stats.useful_tokens += 1
+                    if r.done:
+                        r.finish = now
+            g.state = new_state
+            g.last = nxt[:, None].astype(jnp.int32)
+            self.stats.slot_steps += slots
 
     def _credit(self, r: Request) -> None:
         """Count a completion exactly once, even across resumed runs."""
@@ -605,33 +622,36 @@ class ReconfigurableGroup:
         # each partition admits new work independently the moment it
         # drains, up to its own slot budget; a stalled part's slots are
         # busy receiving migrated KV and admit nothing
-        for i, p in enumerate(self._parts):
-            if self._stall[i] > 0:
-                continue
-            if self._part_done(p):
-                self._retire(p)
-                wave = self._prefill_wave(self.effective_slots(i), now,
-                                          part_idx=i)
-                self._parts[i] = wave
-                if wave is not None and self.obs.enabled:
-                    self.obs.emit("admission", gid=self.gid, part=i,
-                                  tick=now, n=len(wave.requests),
-                                  rids=[r.rid for r in wave.requests])
+        with span("group.admit", gid=self.gid):
+            for i, p in enumerate(self._parts):
+                if self._stall[i] > 0:
+                    continue
+                if self._part_done(p):
+                    self._retire(p)
+                    wave = self._prefill_wave(self.effective_slots(i), now,
+                                              part_idx=i)
+                    self._parts[i] = wave
+                    if wave is not None and self.obs.enabled:
+                        self.obs.emit("admission", gid=self.gid, part=i,
+                                      tick=now, n=len(wave.requests),
+                                      rids=[r.rid for r in wave.requests])
         live = [p for p in self._parts if p is not None]
         if not live:
             return IDLE
         if self.mode == "dynamic" and dynamic and self.acfg.enabled:
-            rem = np.concatenate([p.remaining for p in live])
-            fv = FeatureVector.from_group(rem, len(self.queue),
-                                          self._arrivals.rate(now),
-                                          self.capacity)
-            # a group can only be partitioned as far as it has requests
-            cap = min(self.space.max_ways, rem.size)
-            self.controller.observe(fv, max_ways_now=cap)
+            with span("group.control", gid=self.gid):
+                rem = np.concatenate([p.remaining for p in live])
+                fv = FeatureVector.from_group(rem, len(self.queue),
+                                              self._arrivals.rate(now),
+                                              self.capacity)
+                # a group can only be partitioned as far as it has requests
+                cap = min(self.space.max_ways, rem.size)
+                self.controller.observe(fv, max_ways_now=cap)
             desired = self.controller.state.topology
             if desired != self.topology:
                 prev = self.topology
-                self._reconfigure(desired)
+                with span("group.reshard", gid=self.gid):
+                    self._reconfigure(desired)
                 if self.obs.enabled:
                     tr = self.controller.state.transitions
                     gain, reason = 0.0, ""

@@ -6,6 +6,7 @@ acceptance properties: off-mode summaries carry no obs block, full-mode
 traces round-trip through JSONL exactly, and the attribution table
 answers "which decision preceded each topology change".
 """
+import collections
 import json
 
 import jax
@@ -455,3 +456,135 @@ def test_summary_router_state_spills_plumb_through():
     assert s["control"]["admission_spills"] == 4
     s2 = t.summary(groups, [], router_state={"spills": 4})   # no planner
     assert "admission_spills" not in s2["control"]
+
+
+# -- wall-clock spans (repro.obs.spans) ---------------------------------------
+
+SPANS = ("fleet.tick", "fleet.deliver", "fleet.rebalance", "group.admit",
+         "group.prefill", "group.prefill_sync", "group.control",
+         "group.reshard", "group.decode", "group.decode_sync",
+         "fleet.telemetry", "fleet.close", "python.gc")
+# span -> the span it opens inside (fleet.tick holds all but these three)
+PARENT = {"group.prefill": "group.admit",
+          "group.prefill_sync": "group.prefill",
+          "group.decode_sync": "group.decode"}
+
+
+def _profiled(logdir, eng):
+    """Run ``eng`` to the end under the profiler, with one explicit
+    collection inside the trace; return its spans, ``(name, start, end,
+    thread line)`` in start order."""
+    import gc
+    import glob
+    import os
+    jax.profiler.start_trace(logdir)
+    try:
+        eng.run()
+        gc.collect()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                      recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    return sorted((ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                   (plane.name, i))
+                  for plane in pd.planes if plane.name.startswith("/host:")
+                  for i, line in enumerate(plane.lines)
+                  for ev in line.events if ev.name in SPANS)
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """The same migrating, splitting trace through an object and a vec
+    engine, each under the profiler, with the object engine's prefill and
+    decode calls counted."""
+    from repro.models import transformer as T
+    from repro.serve import engine as serve_engine
+    cfg = get_config("qwen3-14b", reduced=True)
+    params, _ = T.init_model(jax.random.PRNGKey(0), cfg)
+    fc = _fleet_cfg("off", engine="object")
+    calls = {"prefill": 0, "decode": 0}
+
+    def counted(fn, key):
+        def call(*a, **k):
+            calls[key] += 1
+            return fn(*a, **k)
+        return call
+
+    saved = serve_engine.jit_prefill, serve_engine.jit_decode
+    serve_engine.jit_prefill = counted(saved[0], "prefill")
+    serve_engine.jit_decode = counted(saved[1], "decode")
+    try:
+        eng_o = FleetEngine(cfg, params, fleet=fc)
+        eng_o.submit(imbalanced_trace(40, cfg.vocab_size, seed=5,
+                                      shards=fc.num_groups))
+        spans_o = _profiled(str(tmp_path_factory.mktemp("object")), eng_o)
+    finally:
+        serve_engine.jit_prefill, serve_engine.jit_decode = saved
+    eng_v = FleetEngine(cfg, None, fleet=fc.replace(engine="vec"))
+    eng_v.submit(imbalanced_trace(40, cfg.vocab_size, seed=5,
+                                  shards=fc.num_groups))
+    spans_v = _profiled(str(tmp_path_factory.mktemp("vec")), eng_v)
+    return eng_o, spans_o, calls, eng_v, spans_v
+
+
+def test_spans_are_inert_without_a_profiler_and_gc_hook_installs_once():
+    import gc
+    from repro.obs import install_gc_spans, span
+    from repro.obs.spans import GcSpans
+    with span("group.decode", gid=0, part=1):
+        pass
+    install_gc_spans()
+    install_gc_spans()
+    assert sum(isinstance(cb, GcSpans) for cb in gc.callbacks) == 1
+    gc.collect()                         # the hook runs outside a trace too
+
+
+def test_engine_spans_nest_as_documented(profiled):
+    _, spans, _, _, _ = profiled
+    assert {n for n, *_ in spans} == set(SPANS)
+
+    def inside(child, parent_name):
+        _, s, e, line = child
+        return any(n == parent_name and ln == line and ps <= s and e <= pe
+                   for n, ps, pe, ln in spans)
+
+    for sp in spans:
+        name = sp[0]
+        if name in ("fleet.tick", "fleet.close", "python.gc"):
+            continue
+        assert inside(sp, "fleet.tick"), sp
+        if name in PARENT:
+            assert inside(sp, PARENT[name]), sp
+    assert not any(inside(sp, "fleet.tick") for sp in spans
+                   if sp[0] == "fleet.close")
+
+
+def test_sync_spans_count_the_device_calls(profiled):
+    eng, spans, calls, _, _ = profiled
+    count = collections.Counter(n for n, *_ in spans)
+    assert calls["decode"] > 0 and calls["prefill"] > 0
+    assert count["group.decode_sync"] == count["group.decode"] \
+        == calls["decode"]
+    assert count["group.prefill_sync"] == count["group.prefill"] \
+        == calls["prefill"]
+    assert count["fleet.close"] == 1
+    assert count["group.reshard"] == sum(g.stats.splits + g.stats.fuses
+                                         + g.stats.resizes
+                                         for g in eng.groups) > 0
+
+
+def test_requests_are_stamped_at_submit_and_first_admission(profiled):
+    for eng in (profiled[0], profiled[3]):
+        assert eng.requests
+        for r in eng.requests:
+            assert r.submitted_s is not None and r.admitted_s is not None
+            assert r.submitted_s <= r.admitted_s
+
+
+def test_vec_engine_emits_the_same_control_spans(profiled):
+    _, spans_o, _, _, spans_v = profiled
+    for name in ("group.admit", "group.control", "group.reshard"):
+        n_o = sum(1 for n, *_ in spans_o if n == name)
+        n_v = sum(1 for n, *_ in spans_v if n == name)
+        assert n_o == n_v > 0, name
