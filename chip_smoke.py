@@ -138,8 +138,7 @@ class CompileCounter:
 
 
 def _block_on_engine(eng: FleetEngine) -> None:
-    jax.block_until_ready([p.state for g in eng.groups
-                           for p in g._parts if p is not None])
+    jax.block_until_ready(eng.pool.state)
 
 
 def serve(cfg, params, seed: int) -> dict:

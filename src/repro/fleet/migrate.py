@@ -34,10 +34,11 @@ The planner is pure decision logic over a small group *protocol* —
 ``queue``, ``topology``, ``part_live(i)``, ``stats``, ``can_insert``,
 ``extract_live``, ``insert_live``, ``submit(..., part=)`` — implemented
 by :class:`repro.serve.engine.ReconfigurableGroup` and by lightweight
-fakes in the test suite.  Execution (the actual KV-slice surgery via
-``repro.serve.state_utils``) happens in :meth:`MigrationPlanner.execute`,
-invoked by ``FleetEngine.run`` between ticks with the plans the
-``FleetController`` gathered on its rebalance tick.
+fakes in the test suite.  Execution (the row hand-over between parts:
+inside one slot pool a re-label, between two pools a one-row copy)
+happens in :meth:`MigrationPlanner.execute`, invoked by
+``FleetEngine.run`` between ticks with the plans the ``FleetController``
+gathered on its rebalance tick.
 """
 from __future__ import annotations
 
@@ -507,12 +508,11 @@ class MigrationPlanner:
         src, dst = groups[m.src[0]], groups[m.dst[0]]
         if m.dst[1] is None or not dst.can_insert(m.dst[1]):
             return 0
-        row = src.extract_live(m.request)
-        if row is None:
+        moved = src.extract_live(m.request)
+        if moved is None:
             return 0
-        state, last = row
-        ok = dst.insert_live(m.request, state, last,
-                             part=m.dst[1], stall=m.stall)
+        ok = dst.insert_live(m.request, *moved, part=m.dst[1],
+                             stall=m.stall)
         assert ok, "insert_live failed after can_insert passed"
         self.live_migrations += 1
         self.stall_ticks_charged += m.stall

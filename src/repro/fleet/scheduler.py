@@ -43,9 +43,11 @@ contention.  When ``FleetConfig.migrate.enabled``, the chip-level
 live-migration plans each rebalance tick and the engine executes them
 between decode ticks (see :mod:`repro.fleet.migrate`).
 
-All pairs share one jitted ``decode_step`` (same params, same model), so
-the XLA compile cache is shared across the fleet exactly as the paper's
-SMs share one instruction front-end.
+All pairs decode from one :class:`~repro.serve.engine.SlotPool` of
+``num_groups x capacity`` rows: each group's ``step`` marks the parts
+that decode, and the engine makes one ``decode_step`` call per tick over
+the whole pool, so the weights are read once per tick however many parts
+the topologies hold — the paper's SMs sharing one instruction front-end.
 """
 from __future__ import annotations
 
@@ -65,7 +67,8 @@ from repro.models import transformer as T
 from repro.obs.events import OBS_MODES, EventLog
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.spans import install_gc_spans, span
-from repro.serve.engine import IDLE, TICKED, ReconfigurableGroup, Request
+from repro.serve.engine import (IDLE, TICKED, ReconfigurableGroup, Request,
+                                SlotPool)
 
 
 # -- routing policies ----------------------------------------------------------
@@ -243,11 +246,21 @@ class FleetEngine:
         self.params = params
         self.rt = rt
         self.fleet = fleet
-        # object groups share the process-wide compiled decode (one program
-        # per batch shape; see serve.engine.jit_decode).  The vec engine
-        # never decodes tokens, so it tolerates params=None.
+        # object groups decode from one shared pool of rows, one call per
+        # tick; the vec engine never decodes tokens, so it builds no pool
+        # and tolerates params=None.  A part admits at most its effective
+        # slots and re-cuts keep each part within its share, so the pool
+        # holds every group's capacity; a lease at most doubles a part's
+        # slots (a borrower's headroom is its own budget), and rows
+        # admitted on borrowed slots finish where they are after the
+        # slots go home, so with leases each part may hold twice its slots
         self._vec = VecState(fleet.num_groups, fleet.capacity) \
             if fleet.engine == "vec" else None
+        part_rows = fleet.capacity * (2 if fleet.lease.enabled else 1)
+        self.pool = SlotPool(model_cfg, params, rt,
+                             rows=fleet.num_groups * part_rows,
+                             window=fleet.window, wave=part_rows) \
+            if self._vec is None else None
         # chip-wide control plane: one replay buffer and one policy object
         # shared by every group, so online learning pools all samples
         self.telemetry = FleetTelemetry(
@@ -278,7 +291,7 @@ class FleetEngine:
         grp_kw = dict(rt=rt, amoeba=fleet.amoeba, capacity=fleet.capacity,
                       window=fleet.window, mode=fleet.mode,
                       policy=self.policy, replay=grp_replay,
-                      obs=self.obs)
+                      obs=self.obs, pool=self.pool)
         if self._vec is not None:
             self.groups = [
                 VecGroup(model_cfg, params, gid=i, vec_state=self._vec,
@@ -394,20 +407,21 @@ class FleetEngine:
     # -- main loop ----------------------------------------------------------------
 
     def _step_groups(self, dynamic: bool) -> List[str]:
-        """Advance every group one tick; vec mode batches the decode.
+        """Advance every group one tick, then decode once for all.
 
-        In vec mode each group's ``step()`` only runs control flow
-        (admission, controller, stall bookkeeping) and *marks* its
-        decoding parts; the single ``decode_tick`` then applies every
-        mark with one masked array pass.  Deferring is equivalent to the
-        object engine's in-loop decodes because a decode only touches
-        its own group's rows and nothing reads another group's
-        post-decode state within the same tick.
+        Each group's ``step()`` runs control flow (admission, controller,
+        stall bookkeeping) and *marks* its decoding parts; then one pool
+        decode (object) or one masked array pass (vec) serves every
+        mark.  Deferring the decode behind the steps is exact because a
+        decode only touches its own group's rows and nothing reads
+        another group's post-decode state within the same tick.
         """
         statuses = [g.step(dynamic=dynamic, now=self.wall)
                     for g in self.groups]
         if self._vec is not None:
             self._vec.decode_tick(self.wall, self.groups)
+        else:
+            self.pool.decode(self.wall)
         return statuses
 
     def _tick(self, dynamic: bool, max_ticks: int) -> bool:
@@ -504,6 +518,16 @@ class FleetEngine:
     @property
     def slot_steps(self) -> int:
         return sum(g.stats.slot_steps for g in self.groups)
+
+    @property
+    def decode_calls(self) -> int:
+        """Decode calls made (one per tick with a live row; 0 for vec)."""
+        return self.pool.calls if self.pool is not None else 0
+
+    @property
+    def decode_parts(self) -> int:
+        """(group, part) cohorts those calls served."""
+        return self.pool.parts if self.pool is not None else 0
 
 
 # -- chip-configuration comparison ---------------------------------------------

@@ -2,7 +2,7 @@
 
 ``FleetEngine`` with ``FleetConfig.engine = "object"`` advances one wall
 tick via nested Python loops over groups, parts, and requests, paying a
-jitted ``decode_step`` call per part per tick.  That is the right
+jitted ``decode_step`` call over the fleet's slot pool.  That is the right
 fidelity for token-level work but the wrong cost model for *scheduling*
 studies: every quantity the benchmarks compare — completions, latency
 percentiles, slot-steps, steal counters — depends only on request
@@ -56,10 +56,6 @@ PENDING = 0      # registered, not yet delivered to any group queue
 QUEUED = 1       # sitting in a group's admission queue
 LIVE = 2         # admitted: decoding (or stalled) on a part
 DONE = 3         # finished; finish tick stamped
-
-
-def _no_decode(*_a, **_k):  # pragma: no cover - guard, never called
-    raise RuntimeError("vec engine has no jax decode path")
 
 
 class TrackedQueue(collections.deque):
@@ -302,10 +298,12 @@ class VecGroup(ReconfigurableGroup):
 
     def __init__(self, model_cfg, params=None, *, vec_state: VecState,
                  **kw):
-        kw.setdefault("decode_fn", _no_decode)
         super().__init__(model_cfg, params, **kw)
         self.vs = vec_state
         self.queue: TrackedQueue = TrackedQueue()
+
+    def _make_pool(self) -> None:
+        return None                     # no device rows: nothing decodes
 
     # -- admission -------------------------------------------------------------
 

@@ -68,14 +68,24 @@ def init_attention(key, cfg: ModelConfig, cross: bool = False):
 
 
 def _project_qkv(params, x, cfg: ModelConfig, positions, kv_source=None,
-                 apply_positions=True):
-    """Returns q (B,S,H,hd), k/v (B,Skv,KV,hd) with norm+rope applied."""
+                 apply_positions=True, barrier=False):
+    """Returns q (B,S,H,hd), k/v (B,Skv,KV,hd) with norm+rope applied.
+
+    ``barrier`` ends the q/k projections as plain matmuls before the
+    per-head reshape and norm (the one-token decode: fused with them, XLA
+    on TPU copies each layer's wq and wk into a transposed layout on
+    every call).
+    """
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim
     src = x if kv_source is None else kv_source
-    q = (x @ params["wq"]).reshape(B, S, cfg.num_heads, hd)
-    k = (src @ params["wk"]).reshape(B, src.shape[1], cfg.num_kv_heads, hd)
+    q = x @ params["wq"]
+    k = src @ params["wk"]
     v = (src @ params["wv"]).reshape(B, src.shape[1], cfg.num_kv_heads, hd)
+    if barrier and cfg.qk_norm and "q_norm" in params:
+        q, k = jax.lax.optimization_barrier((q, k))
+    q = q.reshape(B, S, cfg.num_heads, hd)
+    k = k.reshape(B, src.shape[1], cfg.num_kv_heads, hd)
     if cfg.qk_norm and "q_norm" in params:
         q = layers.rmsnorm_headwise(params["q_norm"], q, cfg.norm_eps)
         k = layers.rmsnorm_headwise(params["k_norm"], k, cfg.norm_eps)
@@ -244,25 +254,36 @@ def _ring_valid(pos: jnp.ndarray, W: int, slots: jnp.ndarray) -> jnp.ndarray:
     return p >= 0
 
 
-def _write_slot_update(buf, new_val, bidx, clamped, in_range):
-    cur = buf[bidx, clamped]
+def _write_slot_update(buf, new_val, bidx, clamped, in_range, layer=None):
+    at = (bidx, clamped) if layer is None else (layer, bidx, clamped)
+    cur = buf[at]
     val = jnp.where(jnp.reshape(in_range, (-1,) + (1,) * (cur.ndim - 1)),
                     new_val, cur)
-    return buf.at[bidx, clamped].set(val)
+    return buf.at[at].set(val)
+
+
+def _layer(x, layer):
+    """Layer ``layer`` of a stacked cache array (``x`` itself when None)."""
+    if x is None or layer is None:
+        return x
+    return jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=False)
 
 
 def _decode_core(q, cache: KVCache, new_k, new_v, pos, *, W, offset,
-                 s_loc, update, axis=None):
+                 s_loc, update, axis=None, layer=None):
     """Scores one KV shard; LSE-combines across 'model' when mapped.
 
-    q: (B,1,H,hd) -> internally (B,KV,G,hd); cache arrays: (B,s_loc,KV,*).
-    Handles both bf16 and int8-quantized (k_scale/v_scale) caches.
+    q: (B,1,H,hd) -> internally (B,KV,G,hd); cache arrays: (B,s_loc,KV,*),
+    or (L,B,s_loc,KV,*) stacked over layers with ``layer`` the one to use:
+    the new slot is then written into the stack in place and the layer is
+    read from it, so a donated stack is never copied.  Handles both bf16
+    and int8-quantized (k_scale/v_scale) caches.
     """
     B, _, H, hd = q.shape
     k_cache, v_cache = cache.k, cache.v
     ks, vs = cache.k_scale, cache.v_scale
     quant = ks is not None
-    KV = k_cache.shape[2]
+    KV = k_cache.shape[-2]
     G = H // KV
     slots = offset + jnp.arange(s_loc)
 
@@ -271,22 +292,24 @@ def _decode_core(q, cache: KVCache, new_k, new_v, pos, *, W, offset,
         in_range = (write_slot >= 0) & (write_slot < s_loc)
         clamped = jnp.clip(write_slot, 0, s_loc - 1)
         bidx = jnp.arange(B)
+        write = partial(_write_slot_update, bidx=bidx, clamped=clamped,
+                        in_range=in_range, layer=layer)
         if quant:
             nk_q, nk_s = _quantize_kv(new_k[:, 0])
             nv_q, nv_s = _quantize_kv(new_v[:, 0])
-            k_cache = _write_slot_update(k_cache, nk_q, bidx, clamped, in_range)
-            v_cache = _write_slot_update(v_cache, nv_q, bidx, clamped, in_range)
-            ks = _write_slot_update(ks, nk_s, bidx, clamped, in_range)
-            vs = _write_slot_update(vs, nv_s, bidx, clamped, in_range)
+            k_cache = write(k_cache, nk_q)
+            v_cache = write(v_cache, nv_q)
+            ks = write(ks, nk_s)
+            vs = write(vs, nv_s)
         else:
-            k_cache = _write_slot_update(k_cache, new_k[:, 0], bidx, clamped,
-                                         in_range)
-            v_cache = _write_slot_update(v_cache, new_v[:, 0], bidx, clamped,
-                                         in_range)
+            k_cache = write(k_cache, new_k[:, 0])
+            v_cache = write(v_cache, new_v[:, 0])
 
     valid = _ring_valid(pos, W, slots)                       # (B, s_loc)
-    kf = _dequantize_kv(k_cache, ks) if quant else k_cache
-    vf = _dequantize_kv(v_cache, vs) if quant else v_cache
+    kl, vl = _layer(k_cache, layer), _layer(v_cache, layer)
+    ksl, vsl = _layer(ks, layer), _layer(vs, layer)
+    kf = _dequantize_kv(kl, ksl) if quant else kl
+    vf = _dequantize_kv(vl, vsl) if quant else vl
     qg = q.reshape(B, KV, G, hd) / math.sqrt(hd)
     s = jnp.einsum("bkgh,bskh->bkgs", qg, kf,
                    preferred_element_type=jnp.float32)
@@ -311,18 +334,20 @@ def _decode_core(q, cache: KVCache, new_k, new_v, pos, *, W, offset,
 def decode_attention(params, cache: KVCache, x_new: jnp.ndarray,
                      pos: jnp.ndarray, cfg: ModelConfig, *,
                      update: bool = True, cross: bool = False,
-                     rope_pos: Optional[jnp.ndarray] = None
-                     ) -> Tuple[jnp.ndarray, KVCache]:
+                     rope_pos: Optional[jnp.ndarray] = None,
+                     layer=None) -> Tuple[jnp.ndarray, KVCache]:
     """One-token attention step.
 
     x_new: (B, 1, D); pos: (B,) absolute position of the new token (drives
     the ring-slot layout); rope_pos overrides the RoPE angle position when
-    it differs from the ring position (M-RoPE vision offset).
+    it differs from the ring position (M-RoPE vision offset).  With
+    ``layer`` the cache is stacked over layers (a leading axis) and the
+    step reads and updates that layer of it.
     When a mesh is active the cache is seq-sharded over 'model' and the
     softmax is combined with psum; otherwise runs dense locally.
     """
     B = x_new.shape[0]
-    W = cache.k.shape[1]
+    W = cache.k.shape[1 if layer is None else 2]
     rp = pos if rope_pos is None else rope_pos
     if cross or not cfg.uses_rope:
         positions = None
@@ -331,7 +356,8 @@ def decode_attention(params, cache: KVCache, x_new: jnp.ndarray,
         positions = jnp.broadcast_to(rp[:, None, None], (B, 3, 1))
     else:
         positions = rp[:, None]
-    q, new_k, new_v = _project_qkv(params, x_new, cfg, positions)
+    q, new_k, new_v = _project_qkv(params, x_new, cfg, positions,
+                                   barrier=True)
     mesh = shardctx.current_mesh()
 
     shardable = (mesh is not None and "model" in mesh.axis_names
@@ -339,7 +365,7 @@ def decode_attention(params, cache: KVCache, x_new: jnp.ndarray,
     if not shardable:
         out, new_cache = _decode_core(
             q, cache, new_k, new_v, pos,
-            W=W, offset=0, s_loc=W, update=update)
+            W=W, offset=0, s_loc=W, update=update, layer=layer)
     else:
         n_model = mesh.shape["model"]
         s_loc = W // n_model
@@ -351,21 +377,23 @@ def decode_attention(params, cache: KVCache, x_new: jnp.ndarray,
             if B % n_bat:
                 bat = None           # unshardable batch (e.g. B=1): replicate
 
-        def shard_fn(q, c, nk, nv, pos):
+        def shard_fn(q, c, nk, nv, pos, li):
             idx = jax.lax.axis_index("model")
             return _decode_core(q, c, nk, nv, pos,
                                 W=W, offset=idx * s_loc, s_loc=s_loc,
-                                update=update, axis="model")
+                                update=update, axis="model",
+                                layer=None if layer is None else li)
 
         quant = cache.k_scale is not None
-        cache_spec = KVCache(k=P(bat, "model"), v=P(bat, "model"),
-                             k_scale=P(bat, "model") if quant else None,
-                             v_scale=P(bat, "model") if quant else None)
+        sp = P(bat, "model") if layer is None else P(None, bat, "model")
+        cache_spec = KVCache(k=sp, v=sp, k_scale=sp if quant else None,
+                             v_scale=sp if quant else None)
         out, new_cache = jax.shard_map(
             shard_fn, mesh=mesh,
-            in_specs=(P(bat), cache_spec, P(bat), P(bat), P(bat)),
+            in_specs=(P(bat), cache_spec, P(bat), P(bat), P(bat), P()),
             out_specs=(P(bat), cache_spec),
-        )(q, cache, new_k, new_v, pos)
+        )(q, cache, new_k, new_v, pos,
+          jnp.int32(0) if layer is None else layer)
 
     out = out.reshape(B, 1, -1) @ params["wo"]
     return out, new_cache

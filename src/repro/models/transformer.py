@@ -238,26 +238,44 @@ def block_forward(params, x, positions, encoder_out, cfg: ModelConfig,
 
 
 def block_decode(params, state, x_new, pos, cfg: ModelConfig, kind: str,
-                 rt: Runtime, rope_pos=None):
-    """One-token block step. x_new: (B,1,D). Returns (x, new_state)."""
+                 rt: Runtime, rope_pos=None, layer=None):
+    """One-token block step. x_new: (B,1,D). Returns (x, new_state).
+
+    With ``layer`` the state's leaves are stacked over layers (a leading
+    axis) and the step reads and updates that layer; the self-attention
+    cache is written in place in the stack (see ``decode_attention``).
+    """
+    def at_layer(tree):
+        return tree if layer is None else jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, False), tree)
+
+    def put_layer(stack, tree):
+        return tree if layer is None else jax.tree.map(
+            lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, layer, 0),
+            stack, tree)
+
     h = layers.rmsnorm(params["norm1"], x_new, cfg.norm_eps)
     new_state = dict(state)
     if kind == "attn":
         mix, new_state["self"] = attention.decode_attention(
-            params["mixer"], state["self"], h, pos, cfg, rope_pos=rope_pos)
+            params["mixer"], state["self"], h, pos, cfg, rope_pos=rope_pos,
+            layer=layer)
     elif kind == "ssm":
-        mix, new_state["self"] = ssm.ssm_step(
-            params["mixer"], state["self"], h, cfg)
+        mix, ns = ssm.ssm_step(params["mixer"], at_layer(state["self"]), h,
+                               cfg)
+        new_state["self"] = put_layer(state["self"], ns)
     else:
-        mix, new_state["self"] = rglru.rglru_step(
-            params["mixer"], state["self"], h, cfg)
+        mix, ns = rglru.rglru_step(params["mixer"], at_layer(state["self"]),
+                                   h, cfg)
+        new_state["self"] = put_layer(state["self"], ns)
     x = x_new + mix
     if "cross" in state:
+        cross = at_layer(state["cross"])
         h = layers.rmsnorm(params["cross_norm"], x, cfg.norm_eps)
-        enc_len = state["cross"].k.shape[1]
+        enc_len = cross.k.shape[1]
         enc_pos = jnp.full((x.shape[0],), enc_len, jnp.int32)
         out, _ = attention.decode_attention(
-            params["cross_attn"], state["cross"], h, enc_pos, cfg,
+            params["cross_attn"], cross, h, enc_pos, cfg,
             update=False, cross=True)
         x = x + out
     if "ffn" in params:
@@ -682,16 +700,22 @@ def decode_step(params, state: DecodeState, new_tokens: jnp.ndarray,
 
     new_reps = ()
     if state.reps:
-        def rep_body(x, inp):
-            rep_params, rep_states = inp
+        # the stacked layer states ride in the carry and each layer's step
+        # updates its slot of the stack, so a donated state is updated in
+        # place (as the scan's inputs and outputs, XLA keeps a second copy
+        # of every cache, and a layer sliced out and written back is
+        # copied twice)
+        def rep_body(carry, rep_params):
+            x, reps, i = carry
             new_states = []
-            for i, kind in enumerate(pattern):
-                x, ns = block_decode(rep_params[i], rep_states[i], x, pos,
-                                     cfg, kind, rt, rope_pos=rope_pos)
+            for j, kind in enumerate(pattern):
+                x, ns = block_decode(rep_params[j], reps[j], x, pos, cfg,
+                                     kind, rt, rope_pos=rope_pos, layer=i)
                 new_states.append(ns)
-            return x, tuple(new_states)
+            return (x, tuple(new_states), i + 1), None
 
-        x, new_reps = jax.lax.scan(rep_body, x, (params["reps"], state.reps))
+        (x, new_reps, _), _ = jax.lax.scan(
+            rep_body, (x, state.reps, jnp.int32(0)), params["reps"])
 
     new_rest = []
     for j, p in enumerate(params.get("rest", ())):

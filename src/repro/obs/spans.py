@@ -23,8 +23,9 @@ Span names (the layer each one times):
 * ``group.control`` — the controller's features and ``observe`` (control
   plane);
 * ``group.reshard`` — the KV re-partition of a split or fuse (KV reshard);
-* ``group.decode`` — one part's decode call, argmax and token appends
-  (decode step, host loop), around its ``group.decode_sync`` (the readback);
+* ``group.decode`` — the tick's one decode call over the slot pool, its
+  argmax and row mask, and the token appends (decode step, host loop),
+  around its ``group.decode_sync`` (the readback);
 * ``fleet.telemetry`` — per-tick telemetry and metrics sampling;
 * ``fleet.close`` — the end of every ``run`` call: finalize and summary;
 * ``python.gc`` — each collection of the interpreter's garbage collector,

@@ -17,8 +17,15 @@ run fused ``(8,)``, as the equal pair ``(4, 4)``, or as a heterogeneous
 cut like ``(5, 3)`` — each part owns its slot count, admits from the
 queue on its own, and drains independently.  The fused/split lifecycle
 decisions live in :class:`repro.control.GroupController` — this module
-only *executes* them (prefill waves, KV-state partitioning, decode
-ticks).
+only *executes* them (prefill waves, re-partitioning, decode ticks).
+
+A part is a set of rows of a :class:`SlotPool`, a fixed number of decode
+rows at one KV ring that every group of a fleet shares.  Admission writes
+a prefill's rows into free pool rows; a split, fuse or migration inside
+the pool re-labels rows and moves no KV; and one ``decode_step`` call per
+tick decodes every row of the pool, so the weights are read once per tick
+whatever the topologies.
+
 :class:`ReconfigurableGroup` is the unit the fleet scheduler
 (``repro.fleet``) replicates N times; :class:`ServeEngine` is the N=1
 case and keeps the original public API.
@@ -37,6 +44,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import heapq
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence
@@ -52,6 +60,7 @@ from repro.control import (ArrivalRateTracker, ConfigSpace, FeatureVector,
 from repro.control.policies import ReconfigPolicy
 from repro.core.predictor import LogisticModel
 from repro.models import transformer as T
+from repro.models.attention import KVCache
 from repro.obs.events import NULL_LOG, EventLog
 from repro.obs.spans import span
 from repro.serve import state_utils as su
@@ -116,13 +125,11 @@ class ServeStats:
 
 
 class _Group:
-    """One decode group: live requests + their merged DecodeState."""
+    """One decode part: its requests and the pool row of each."""
 
-    def __init__(self, requests: List[Request], state: T.DecodeState,
-                 last_tokens: jnp.ndarray):
+    def __init__(self, requests: List[Request], rows: List[int]):
         self.requests = requests
-        self.state = state
-        self.last = last_tokens            # (B, 1) next input token per row
+        self.rows = rows
 
     @property
     def remaining(self) -> np.ndarray:
@@ -137,15 +144,214 @@ def _group_done(g: Optional[_Group]) -> bool:
 # compiled programs are keyed on the static config, runtime and KV window
 # and on the batch shape, so every group and engine serving one model
 # shares them (a prefill per wave size and prompt length, a decode step
-# per batch size).
+# per pool width).  The decode's state is donated: the pool is updated in
+# place.
 jit_prefill = jax.jit(T.prefill, static_argnames=("cfg", "rt", "window"))
-jit_decode = jax.jit(T.decode_step, static_argnames=("cfg", "rt"))
+jit_decode = jax.jit(T.decode_step, static_argnames=("cfg", "rt"),
+                     donate_argnums=(1,))
+# the compiled program behind ``jit_decode``, for the pools' ahead-of-time
+# builds: serving calls go through the module's ``jit_decode``, which a
+# caller may replace with a plain function (to count or alter the calls)
+# before building an engine
+_decode_program = jit_decode
 
 
 def make_decode_fn(model_cfg: ModelConfig, rt: T.Runtime) -> Callable:
     """:data:`jit_decode` bound to one model and runtime:
     ``decode(params, state, tokens)``."""
     return functools.partial(jit_decode, cfg=model_cfg, rt=rt)
+
+
+def _put_rows(state, last, src, nxt, rows):
+    """``src``'s rows and their next tokens written into pool rows
+    ``rows``."""
+    return su.put(state, rows, src), last.at[rows, 0].set(nxt)
+
+
+def _advance(logits, pos, last, mask):
+    """After a pool decode: rows in ``mask`` take their argmax as the next
+    token and keep the step's ``pos + 1``; every other row keeps its next
+    token and its position."""
+    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+    return jnp.where(mask, pos, pos - 1), jnp.where(mask[:, None], nxt, last)
+
+
+def _recurrent(state: T.DecodeState):
+    """The leaves a decode advances in every row whatever its position:
+    each ssm or rglru layer's conv and hidden state, as ``(reps, rest)``
+    with None for an attention layer.  (An attention row writes the K/V
+    of its position into that position's ring slot, which decoding a row
+    held at that position again rewrites with the same K/V.)"""
+    def pick(d):
+        return None if isinstance(d["self"], KVCache) else d["self"]
+    return tuple(map(pick, state.reps)), tuple(map(pick, state.rest))
+
+
+def _with_recurrent(state: T.DecodeState, rec) -> T.DecodeState:
+    """``state`` with its recurrent leaves replaced by ``rec``."""
+    def put(d, r):
+        return d if r is None else {**d, "self": r}
+    return state._replace(reps=tuple(map(put, state.reps, rec[0])),
+                          rest=tuple(map(put, state.rest, rec[1])))
+
+
+def _keep_rows(new, old, held):
+    """Recurrent leaves ``new`` with the rows in ``held`` taken from
+    ``old`` (the batch axis is 1 in the stacked ``reps``, 0 in ``rest``)."""
+    def keep(lead):
+        def f(n, o):
+            m = held.reshape((1,) * lead + (-1,) + (1,) * (n.ndim - lead - 1))
+            return jnp.where(m, o, n)
+        return f
+    return (jax.tree.map(keep(1), new[0], old[0]),
+            jax.tree.map(keep(0), new[1], old[1]))
+
+
+jit_put_rows = jax.jit(_put_rows, donate_argnums=(0, 1))
+jit_advance = jax.jit(_advance, donate_argnums=(1, 2))
+jit_copy = jax.jit(lambda tree: jax.tree.map(jnp.copy, tree))
+jit_keep_rows = jax.jit(_keep_rows, donate_argnums=(0,))
+
+# (model, runtime, rows, window, wave) -> a prefill row's state shapes,
+# for every pool whose programs this process has built
+_built: Dict[tuple, T.DecodeState] = {}
+
+
+class SlotPool:
+    """Decode rows at one KV ring, shared by every part of the groups that
+    serve from it and decoded by one call per tick.
+
+    A part holds a list of pool rows.  :meth:`put` writes a prefill's rows
+    into free rows; a re-partition or a migration inside the pool moves
+    row indices only; a drained part's rows go back with :meth:`free`.
+    Each tick the groups :meth:`mark` the parts that decode, and
+    :meth:`decode` runs ``jit_decode`` over every row once: the marked
+    rows' live requests advance, and every other row keeps its position
+    and next token.  A held row that a request still owns (its part
+    stalls, or its group reconfigures this tick) also keeps its recurrent
+    state: where the model has ssm or rglru layers, the decode's update
+    of those rows is undone.  Its attention K/V needs no undoing: the
+    decode rewrote its position's ring slot with the K/V that its next
+    decode writes there again.
+
+    The state and the ``(rows, 1)`` next-token column are donated to every
+    program that updates them, so the pool lives on the device once.
+    Every program at the pool's width (the decode, the row write of each
+    wave size up to ``wave``, the argmax and row mask, the recurrent
+    undo) is built when the pool is made, once per process.  The width is
+    fixed: the owner sizes it to the most rows its groups can hold.
+    """
+
+    def __init__(self, model_cfg: ModelConfig, params, rt: T.Runtime,
+                 rows: int, window: int, wave: int):
+        self.cfg, self.params, self.rt = model_cfg, params, rt
+        self.window, self.wave = window, wave
+        self.rows = rows
+        proto = self._build()
+        self.state = _zeros(su.with_rows(proto, rows))
+        self.last = jnp.zeros((rows, 1), jnp.int32)
+        self._recurrent = bool(jax.tree.leaves(_recurrent(self.state)))
+        self._free = list(range(rows))         # a heap: lowest row first
+        self._marked: List[tuple] = []         # (group, part) this tick
+        self.calls = 0                         # decode calls made
+        self.parts = 0                         # parts those calls served
+
+    def _build(self) -> T.DecodeState:
+        """Compile every program at this width ahead (once per process);
+        returns one prefill row's state shapes."""
+        key = (self.cfg, self.rt, self.rows, self.window, self.wave)
+        if key in _built:
+            return _built[key]
+        proto = jax.eval_shape(
+            lambda p: T.prefill(p, {"tokens": jnp.zeros((1, 1), jnp.int32)},
+                                cfg=self.cfg, rt=self.rt,
+                                window=self.window)[1], self.params)
+        state = su.with_rows(proto, self.rows)
+        last = jax.ShapeDtypeStruct((self.rows, 1), jnp.int32)
+        dec = _decode_program.lower(self.params, state, last, cfg=self.cfg,
+                                    rt=self.rt)
+        dec.compile()
+        logits = dec.out_info[0]
+        mask = jax.ShapeDtypeStruct((self.rows,), jnp.bool_)
+        jit_advance.lower(logits, state.pos, last, mask).compile()
+        rec = _recurrent(state)
+        if jax.tree.leaves(rec):
+            jit_copy.lower(rec).compile()
+            jit_keep_rows.lower(rec, rec, mask).compile()
+        for b in range(1, self.wave + 1):
+            ids = jax.ShapeDtypeStruct((b,), jnp.int32)
+            jit_put_rows.lower(state, last, su.with_rows(proto, b), ids,
+                               ids).compile()
+        _built[key] = proto
+        return proto
+
+    # -- rows --------------------------------------------------------------------
+
+    def alloc(self, n: int) -> List[int]:
+        """``n`` free rows, lowest first."""
+        if len(self._free) < n:
+            raise RuntimeError(f"slot pool of {self.rows} rows: {n} wanted, "
+                               f"{len(self._free)} free")
+        return [heapq.heappop(self._free) for _ in range(n)]
+
+    def free(self, rows: Sequence[int]) -> None:
+        for r in rows:
+            heapq.heappush(self._free, r)
+
+    def put(self, src: T.DecodeState, nxt) -> List[int]:
+        """Write a prefill's rows and their first tokens ``nxt`` into free
+        rows; returns the rows."""
+        rows = self.alloc(int(src.pos.shape[0]))
+        self.state, self.last = jit_put_rows(
+            self.state, self.last, src, nxt, np.asarray(rows, np.int32))
+        return rows
+
+    def row_state(self, row: int):
+        """One row's decode state and next token, copied out (to move it
+        to another pool)."""
+        return su.take(self.state, [row]), np.asarray(self.last)[row]
+
+    # -- the tick's decode -------------------------------------------------------
+
+    def mark(self, group: "ReconfigurableGroup", part: _Group) -> None:
+        """Decode ``part``'s live requests in this tick's call."""
+        self._marked.append((group, part))
+
+    def decode(self, now: int, **ids) -> bool:
+        """One ``jit_decode`` over every row; the marked parts' live
+        requests get their tokens.  Returns False when nothing was
+        marked (no call)."""
+        if not self._marked:
+            return False
+        mask = np.zeros(self.rows, bool)       # rows that advance
+        held = np.ones(self.rows, bool)        # owned rows of unmarked parts
+        held[self._free] = False
+        for _, part in self._marked:
+            for row, r in zip(part.rows, part.requests):
+                mask[row] = not r.done
+                held[row] = False
+        hold = self._recurrent and held.any()
+        with span("group.decode", **ids):
+            saved = jit_copy(_recurrent(self.state)) if hold else None
+            logits, state = jit_decode(self.params, self.state, self.last,
+                                       cfg=self.cfg, rt=self.rt)
+            if hold:
+                state = _with_recurrent(state, jit_keep_rows(
+                    _recurrent(state), saved, held))
+            pos, self.last = jit_advance(logits, state.pos, self.last, mask)
+            self.state = state._replace(pos=pos)
+            with span("group.decode_sync", **ids):
+                tokens = np.asarray(self.last)[:, 0]
+            for group, part in self._marked:
+                group._decoded(part, tokens, now)
+        self.calls += 1
+        self.parts += len(self._marked)
+        self._marked.clear()
+        return True
+
+
+def _zeros(shapes):
+    return jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), shapes)
 
 
 # group step outcomes
@@ -175,6 +381,10 @@ class ReconfigurableGroup:
     N=1 :class:`ServeEngine` or the N-group ``repro.fleet.FleetEngine``)
     owns the wall clock and passes it in as ``now`` so request completion
     times are stamped consistently across groups.
+
+    ``pool`` is the fleet's :class:`SlotPool`, which the fleet decodes
+    after every group has stepped.  Without one the group makes a pool of
+    ``capacity`` rows and decodes it at the end of its own ``step``.
     """
 
     def __init__(self, model_cfg: ModelConfig, params,
@@ -182,11 +392,11 @@ class ReconfigurableGroup:
                  amoeba: AmoebaConfig = AmoebaConfig(),
                  capacity: int = 8, window: int = 256,
                  mode: str = "dynamic", gid: int = 0,
-                 decode_fn: Optional[Callable] = None,
                  policy: Optional[ReconfigPolicy] = None,
                  model: Optional[LogisticModel] = None,
                  replay: Optional[ReplayBuffer] = None,
-                 obs: Optional[EventLog] = None):
+                 obs: Optional[EventLog] = None,
+                 pool: Optional[SlotPool] = None):
         if mode not in ("dynamic", "fused", "split"):
             raise ValueError(f"unknown group mode {mode!r}")
         if mode == "split" and capacity < 2:
@@ -235,7 +445,8 @@ class ReconfigurableGroup:
             replay=grp_replay, label_margin=amoeba.label_margin,
             regroup_policy=amoeba.regroup_policy,
             obs=self.obs, gid=gid)
-        self._decode = decode_fn or make_decode_fn(model_cfg, rt)
+        self._own_pool = self._make_pool() if pool is None else None
+        self._pool = pool or self._own_pool
         self._arrivals = ArrivalRateTracker()
         # the current topology: one entry per partition (None = drained)
         # and the matching per-part decode-slot budget — parts always
@@ -260,6 +471,11 @@ class ReconfigurableGroup:
         self._lease_book = None
         self._lease_touched = False
         self._now_tick = 0             # stamped each step; lease accrual
+
+    def _make_pool(self) -> Optional[SlotPool]:
+        """The decode rows of a group that serves without a fleet."""
+        return SlotPool(self.cfg, self.params, self.rt, rows=self.capacity,
+                        window=self.window, wave=self.capacity)
 
     # -- admission -------------------------------------------------------------
 
@@ -315,14 +531,15 @@ class ReconfigurableGroup:
 
     def _prefill_wave(self, n_slots: int, now: int,
                       part_idx: Optional[int] = None) -> Optional[_Group]:
-        """Admit up to n_slots queued requests: batch prefill per length."""
+        """Admit up to n_slots queued requests: batch prefill per length,
+        each batch's rows written into free pool rows."""
         wave = self._admission_scan(n_slots, part_idx)
         if not wave:
             return None
         by_len: Dict[int, List[Request]] = collections.defaultdict(list)
         for r in wave:
             by_len[len(r.prompt)].append(r)
-        states, lasts, ordered = [], [], []
+        rows, ordered = [], []
         for plen, reqs in sorted(by_len.items()):
             with span("group.prefill", gid=self.gid, part=part_idx):
                 toks = jnp.asarray([r.prompt for r in reqs], jnp.int32)
@@ -339,34 +556,29 @@ class ReconfigurableGroup:
                         r.finish = now
                 self.stats.prefill_tokens += plen * len(reqs)
                 self.stats.useful_tokens += len(reqs)
-                states.append(st)
-                lasts.append(nxt[:, None].astype(jnp.int32))
+                rows.extend(self._pool.put(st, nxt))
                 ordered.extend(reqs)
-        return _Group(ordered, su.concat(states),
-                      jnp.concatenate(lasts, axis=0))
+        return _Group(ordered, rows)
 
     # -- decode ----------------------------------------------------------------
 
     def _tick_group(self, g: _Group, slots: int, now: int,
                     part_idx: int = 0) -> None:
-        """One decode step for every live request in the group."""
-        live = [i for i, r in enumerate(g.requests) if not r.done]
-        if not live:
+        """Mark the part's live requests for this tick's pool decode."""
+        if all(r.done for r in g.requests):
             return
-        with span("group.decode", gid=self.gid, part=part_idx):
-            logits, new_state = self._decode(self.params, g.state, g.last)
-            nxt = jnp.argmax(logits, axis=-1)
-            with span("group.decode_sync", gid=self.gid, part=part_idx):
-                arr = np.asarray(nxt)
-            for i, r in enumerate(g.requests):
-                if not r.done:
-                    r.generated.append(int(arr[i]))
-                    self.stats.useful_tokens += 1
-                    if r.done:
-                        r.finish = now
-            g.state = new_state
-            g.last = nxt[:, None].astype(jnp.int32)
-            self.stats.slot_steps += slots
+        self._pool.mark(self, g)
+        self.stats.slot_steps += slots
+
+    def _decoded(self, g: _Group, tokens: np.ndarray, now: int) -> None:
+        """Append each live request's token from the pool decode
+        (``tokens`` holds one per pool row)."""
+        for row, r in zip(g.rows, g.requests):
+            if not r.done:
+                r.generated.append(int(tokens[row]))
+                self.stats.useful_tokens += 1
+                if r.done:
+                    r.finish = now
 
     def _credit(self, r: Request) -> None:
         """Count a completion exactly once, even across resumed runs."""
@@ -375,8 +587,13 @@ class ReconfigurableGroup:
             self.stats.completed += 1
 
     def _retire(self, g: Optional[_Group]) -> None:
-        for r in (g.requests if g else []):
+        """Credit a drained part's requests and free its pool rows."""
+        if g is None:
+            return
+        for r in g.requests:
             self._credit(r)
+        if self._pool is not None:
+            self._pool.free(g.rows)
 
     def _part_done(self, g) -> bool:
         """Is this part drained (empty or all members done)?
@@ -391,12 +608,12 @@ class ReconfigurableGroup:
     def _reconfigure(self, target: Topology) -> None:
         """Merge all live partitions and re-partition onto ``target``.
 
-        Executes the controller's decision: the KV states of the live
-        parts are concatenated and re-sliced along the batch axis into
-        parts sized to the target composition's slot budgets (a
-        ``(5, 3)`` cut quarantines the long tail on 3 slots), so
-        reconfiguration never changes any request's results — only which
-        rows decode in lockstep and how many slots each cohort owns.
+        Executes the controller's decision: the live parts' pool rows are
+        merged and re-labelled into parts sized to the target
+        composition's slot budgets (a ``(5, 3)`` cut quarantines the long
+        tail on 3 slots).  No KV moves, so reconfiguration never changes
+        any request's results — only which rows admit and drain together
+        and how many slots each cohort owns.
         """
         # leases are defined against the *current* composition; a new cut
         # invalidates every book entry, so the planner force-revokes both
@@ -435,21 +652,18 @@ class ReconfigurableGroup:
         self._borrowed = [0] * len(self._slots)
 
     def _merge_parts(self, live: List[_Group]) -> _Group:
-        """Concatenate live parts (in part order) into one batch."""
+        """Join live parts (in part order) into one."""
         if len(live) == 1:
             return live[0]
-        return _Group(
-            sum((p.requests for p in live), []),
-            su.concat([p.state for p in live]),
-            jnp.concatenate([p.last for p in live], axis=0))
+        return _Group(sum((p.requests for p in live), []),
+                      sum((p.rows for p in live), []))
 
     def _make_part(self, merged: _Group, ids: List[int]) -> Optional[_Group]:
-        """Slice one re-partitioned part out of the merged batch."""
+        """One re-partitioned part: members ``ids`` of the merged one."""
         if not ids:
             return None
         return _Group([merged.requests[i] for i in ids],
-                      su.take(merged.state, ids),
-                      jnp.take(merged.last, jnp.asarray(ids), axis=0))
+                      [merged.rows[i] for i in ids])
 
     # -- introspection (used by the fleet router and telemetry) ----------------
 
@@ -547,13 +761,14 @@ class ReconfigurableGroup:
                 and len(self.part_live(part)) < self.effective_slots(part))
 
     def extract_live(self, req: Request):
-        """Remove one in-flight request and return its decode state.
+        """Remove one in-flight request from its part.
 
-        Returns ``(state_row, last_row)`` — the request's KV slice and
-        next-token row, batch axis kept — or ``None`` when the request is
-        not live here (already finished or never admitted).  The source
-        part keeps its other members untouched; a part drained by the
-        extraction frees its slots immediately.
+        Returns ``(pool, row)`` — the slot pool and the row that hold the
+        request's decode state, still allocated for :meth:`insert_live`
+        — or ``None`` when the request is not live here (already finished
+        or never admitted).  The source part keeps its other members
+        untouched; a part drained by the extraction frees its slots
+        immediately.
         """
         for i, g in enumerate(self._parts):
             if g is None:
@@ -561,31 +776,33 @@ class ReconfigurableGroup:
             for j, r in enumerate(g.requests):
                 if r is req and not r.done:
                     rest = [k for k in range(len(g.requests)) if k != j]
-                    state_row, rest_state = su.split(g.state, [j], rest)
-                    last_row = g.last[j:j + 1]
-                    if rest:
-                        self._parts[i] = _Group(
-                            [g.requests[k] for k in rest], rest_state,
-                            jnp.take(g.last, jnp.asarray(rest), axis=0))
-                    else:
-                        self._parts[i] = None
+                    self._parts[i] = _Group(
+                        [g.requests[k] for k in rest],
+                        [g.rows[k] for k in rest]) if rest else None
                     self.stats.migrations_out += 1
-                    return state_row, last_row
+                    return self._pool, g.rows[j]
         return None
 
-    def insert_live(self, req: Request, state, last, part: int,
+    def insert_live(self, req: Request, pool: SlotPool, row: int, part: int,
                     stall: int = 0) -> bool:
         """Graft a migrated in-flight request onto part ``part``.
 
-        The destination part's slots stall for ``stall`` ticks — the KV
-        transfer cost — before decoding resumes.  Done-but-unretired
-        rows are compacted out first so the part's decode batch never
+        ``pool`` and ``row`` are what :meth:`extract_live` returned.  In
+        this group's own pool the row changes owner and nothing moves;
+        from another pool it is copied into a free row here.  The
+        destination part's slots stall for ``stall`` ticks — the KV
+        transfer cost the control plane prices — before decoding resumes.
+        Done-but-unretired rows are compacted out first so the part never
         outgrows its slot budget.  Returns False (no state change) when
         the part has no free slot.
         """
         if not self.can_insert(part):
             return False
         req.part_affinity = None
+        if pool is not self._pool:
+            state, last = pool.row_state(row)
+            pool.free([row])
+            row, = self._pool.put(state, last)
         g = self._parts[part]
         if g is not None:
             live = [k for k, r in enumerate(g.requests) if not r.done]
@@ -593,16 +810,14 @@ class ReconfigurableGroup:
                 for r in g.requests:
                     if r.done:
                         self._credit(r)
+                self._pool.free([row_ for row_, r in zip(g.rows, g.requests)
+                                 if r.done])
                 g = _Group([g.requests[k] for k in live],
-                           su.take(g.state, live),
-                           jnp.take(g.last, jnp.asarray(live), axis=0)) \
-                    if live else None
+                           [g.rows[k] for k in live]) if live else None
         if g is None:
-            self._parts[part] = _Group([req], state, last)
+            self._parts[part] = _Group([req], [row])
         else:
-            self._parts[part] = _Group(
-                g.requests + [req], su.concat([g.state, state]),
-                jnp.concatenate([g.last, last], axis=0))
+            self._parts[part] = _Group(g.requests + [req], g.rows + [row])
         self._stall[part] = max(self._stall[part], int(stall))
         self.stats.migrations_in += 1
         return True
@@ -677,6 +892,8 @@ class ReconfigurableGroup:
                 continue
             if p is not None:
                 self._tick_group(p, self._slot_charge(i), now, part_idx=i)
+        if self._own_pool is not None:
+            self._own_pool.decode(now, gid=self.gid)
         self.stats.ticks += 1
         return TICKED
 

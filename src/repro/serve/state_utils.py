@@ -13,7 +13,6 @@ from typing import Callable, List, Sequence
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.models.transformer import DecodeState
 
@@ -49,15 +48,25 @@ def concat(states: List[DecodeState]) -> DecodeState:
     )
 
 
-def split(state: DecodeState, take_ids: Sequence[int],
-          keep_ids: Sequence[int]):
-    """Partition the batch axis into (taken, kept) states.
+def with_rows(state: DecodeState, n: int) -> DecodeState:
+    """The shapes and dtypes of ``state`` with ``n`` rows (nothing is
+    allocated)."""
+    sds = jax.ShapeDtypeStruct
+    return _map_batch(state,
+                      lambda x: sds((n,) + x.shape[1:], x.dtype),
+                      lambda x: sds(x.shape[:1] + (n,) + x.shape[2:],
+                                    x.dtype))
 
-    The extraction primitive of live migration: the migrating rows
-    travel as ``taken`` while ``kept`` stays on the source part.
-    """
-    return take(state, take_ids), take(state, keep_ids)
 
+def put(state: DecodeState, rows, src: DecodeState) -> DecodeState:
+    """``state`` with row ``rows[i]`` replaced by row ``i`` of ``src``."""
+    def at(x, y, lead):
+        ix = (slice(None),) * lead + (rows,)
+        return x.at[ix].set(y, unique_indices=True)
 
-def batch_size(state: DecodeState) -> int:
-    return int(state.pos.shape[0])
+    return DecodeState(
+        pos=at(state.pos, src.pos, 0),
+        rope_offset=at(state.rope_offset, src.rope_offset, 0),
+        reps=jax.tree.map(lambda x, y: at(x, y, 1), state.reps, src.reps),
+        rest=jax.tree.map(lambda x, y: at(x, y, 0), state.rest, src.rest),
+    )

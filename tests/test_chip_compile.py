@@ -97,3 +97,27 @@ def test_qwen3_decode_step_compiles_for_v5e(one_chip):
     mem = c.memory_analysis()
     weights = cfg.param_count() * 2
     assert weights <= mem.argument_size_in_bytes < weights + 2**27
+
+
+@pytest.mark.parametrize("layers", [1, 2])
+def test_pool_decode_updates_its_state_in_place_on_v5e(one_chip, layers):
+    """The fleet's pool decode at qwen3-14b width (32 rows, ring 1024):
+    the donated state comes back aliased, so the program adds no second
+    copy of the K/V (128 MiB a layer), the layer loop's included, and no
+    copy of a layer's weights (a transposed ``wq`` is 50 MiB)."""
+    cfg = QWEN3.replace(num_layers=layers)
+    rt = T.Runtime(production=False, remat=False)
+    shapes, _ = T.model_pspecs(cfg)
+    state = jax.eval_shape(lambda: T.init_decode_state(cfg, 32, 1024))
+    on_chip = lambda t: jax.tree.map(
+        lambda a: _spec(a.shape, a.dtype, one_chip), t)
+    tokens = _spec((32, 1), jnp.int32, one_chip)
+    c = jit_decode.lower(on_chip(shapes), on_chip(state), tokens,
+                         cfg=cfg, rt=rt).compile()
+    mem = c.memory_analysis()
+    kv = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
+    assert kv >= layers * 2**27
+    assert mem.alias_size_in_bytes >= kv
+    assert (mem.temp_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes) < 2**27
+    assert mem.temp_size_in_bytes < 2**24
