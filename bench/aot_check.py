@@ -36,24 +36,25 @@ def check_cell(spec: dict, sharding) -> dict:
     import system
     from repro.serve.engine import jit_decode, jit_prefill
 
-    cfg = system.model_config(spec["cfg"])
+    sut = system.System(spec["cfg"])
+    cfg, rt = sut.cfg, sut.rt
     mix = spec["mix"]
     W = generator.ring_window(mix)
     cap = spec["cfg"]["fleet"]["capacity"]
-    params = _sds(system.param_layout(cfg), sharding)
+    params = _sds(sut.layout(), sharding)
     toks = jax.ShapeDtypeStruct((cap, max(mix["prompt_buckets"])), jnp.int32,
                                 sharding=sharding)
     out = {"window": W, "capacity": cap}
     pre = jit_prefill.lower(params, {"tokens": toks}, cfg=cfg,
-                            rt=system.SERVE_RT, window=W).compile()
+                            rt=rt, window=W).compile()
     out["prefill"] = _mem(pre.memory_analysis())
     state = jax.eval_shape(
         lambda p, t: jit_prefill(p, {"tokens": t}, cfg=cfg,
-                                 rt=system.SERVE_RT, window=W)[1],
+                                 rt=rt, window=W)[1],
         params, toks)
     last = jax.ShapeDtypeStruct((cap, 1), jnp.int32, sharding=sharding)
     dec = jit_decode.lower(params, _sds(state, sharding), last, cfg=cfg,
-                           rt=system.SERVE_RT).compile()
+                           rt=rt).compile()
     out["decode"] = _mem(dec.memory_analysis())
     return out
 

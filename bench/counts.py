@@ -15,6 +15,12 @@ rows or reads only live KV cannot push a share above its roofline:
 
 Model FLOPs count a multiply-add as 2.  Bytes are bf16 (2 per element)
 for weights, K/V, embeddings and logits.
+
+``Shape`` counts a dense decoder layer.  An architecture module
+(``bench/archs/<model_type>.py``) gives its own ``Shape`` with the same
+interface: ``from_config``, ``weight_bytes_per_call`` and the calls
+``prefill_call``, ``decode_call`` and ``decode_rows``.  Readers and
+``run.py`` reach the counts only through those.
 """
 from __future__ import annotations
 
@@ -90,6 +96,45 @@ class Shape:
     def head_flops(self) -> int:
         return 2 * self.head_params
 
+    # -- calls -------------------------------------------------------------
+    def prefill_call(self, rows: int, prompt_len: int) -> "Work":
+        """One prefill call of ``rows`` prompts of ``prompt_len`` tokens."""
+        S = prompt_len
+        causal_pairs = S * (S + 1) // 2
+        flops = rows * (S * self.token_matmul_flops
+                        + self.layers * 4 * self.heads * self.head_dim
+                        * causal_pairs
+                        + self.head_flops)
+        nbytes = (self.weight_bytes_per_call
+                  + rows * S * self.kv_bytes_per_position   # K/V written
+                  + rows * S * self.d * BYTES               # embedding rows
+                  + rows * self.vocab * BYTES)              # last logits
+        return Work(flops=flops, bytes=nbytes, calls=1)
+
+    def decode_call(self, contexts: Sequence[int]) -> "Work":
+        """One decode call; ``contexts`` holds, for each live row, the
+        number of positions its new token attends to (its own included).
+        Done rows are not listed: they count nothing."""
+        if not contexts:
+            return Work()
+        flops = sum(self.token_matmul_flops + self.head_flops
+                    + self.attn_flops(c) for c in contexts)
+        nbytes = self.weight_bytes_per_call + sum(
+            (c - 1) * self.kv_bytes_per_position    # live K/V read
+            + self.kv_bytes_per_position            # the new position written
+            + self.d * BYTES + self.vocab * BYTES   # embedding row, logits
+            for c in contexts)
+        return Work(flops=flops, bytes=nbytes, calls=1)
+
+    def decode_rows(self, contexts: Iterable[int]) -> "Work":
+        """The per-row part of decode work, with no call's weight read:
+        used where rows are known but their grouping into calls is not."""
+        w = self.decode_call(list(contexts))
+        if w.calls:
+            w.bytes -= self.weight_bytes_per_call
+            w.calls = 0
+        return w
+
 
 @dataclass
 class Work:
@@ -106,49 +151,9 @@ class Work:
         return max(self.flops / peak_flops, self.bytes / peak_bw)
 
 
-def prefill_call(s: Shape, rows: int, prompt_len: int) -> Work:
-    """One prefill call of ``rows`` prompts of ``prompt_len`` tokens."""
-    S = prompt_len
-    causal_pairs = S * (S + 1) // 2
-    flops = rows * (S * s.token_matmul_flops
-                    + s.layers * 4 * s.heads * s.head_dim * causal_pairs
-                    + s.head_flops)
-    nbytes = (s.weight_bytes_per_call
-              + rows * S * s.kv_bytes_per_position       # K/V written
-              + rows * S * s.d * BYTES                   # embedding rows
-              + rows * s.vocab * BYTES)                  # last logits
-    return Work(flops=flops, bytes=nbytes, calls=1)
-
-
-def decode_call(s: Shape, contexts: Sequence[int]) -> Work:
-    """One decode call; ``contexts`` holds, for each live row, the number
-    of positions its new token attends to (its own included).  Done rows
-    are not listed: they count nothing."""
-    if not contexts:
-        return Work()
-    flops = sum(s.token_matmul_flops + s.head_flops + s.attn_flops(c)
-                for c in contexts)
-    nbytes = s.weight_bytes_per_call + sum(
-        (c - 1) * s.kv_bytes_per_position       # live K/V read
-        + s.kv_bytes_per_position               # the new position written
-        + s.d * BYTES + s.vocab * BYTES         # embedding row, logits
-        for c in contexts)
-    return Work(flops=flops, bytes=nbytes, calls=1)
-
-
-def decode_rows(s: Shape, contexts: Iterable[int]) -> Work:
-    """The per-row part of decode work, with no call's weight read: used
-    where rows are known but their grouping into calls is not."""
-    w = decode_call(s, list(contexts))
-    if w.calls:
-        w.bytes -= s.weight_bytes_per_call
-        w.calls = 0
-    return w
-
-
 def decode_bandwidth_bound(s: Shape, max_rows: int, max_ctx: int,
                            peak_flops: float, peak_bw: float) -> bool:
     """True when even the largest decode call is bound by bytes: then the
     sum of per-call least times equals the larger of the summed bounds."""
-    w = decode_call(s, [max_ctx] * max_rows)
+    w = s.decode_call([max_ctx] * max_rows)
     return w.flops / peak_flops <= w.bytes / peak_bw

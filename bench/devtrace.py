@@ -13,6 +13,11 @@ traced window sit on the host plane.
 Device events are first put on the host's clock (``align``).  Busy time
 is the union of the operation intervals inside the traced window; the idle share is one minus busy over the window.  Each idle gap
 is named by the host span that covers its midpoint.
+
+On several chips busy time is averaged over the device planes and a
+program's device time is summed over them.  One launch of a program over a
+mesh shows once on every plane it runs on, so its launches are its count
+on the plane that shows it most.
 """
 from __future__ import annotations
 
@@ -117,8 +122,8 @@ def window_of(ev: Events) -> Interval:
 class Reduced:
     window_s: float
     busy_s: float                        # mean over the devices
-    program_s: Dict[str, float]          # device seconds per program
-    program_calls: Dict[str, int]
+    program_s: Dict[str, float]          # device seconds, summed over planes
+    program_calls: Dict[str, int]        # launches
     top_ops: List[Tuple[str, float]]
     idle_gaps: List[Tuple[str, float]]
     devices: int
@@ -161,11 +166,14 @@ def reduce(ev: Events, top: int = 10) -> Reduced:
         ops = dev.ops or dev.modules
         busy = union(clip([(s, e) for _, s, e in ops], lo, hi))
         busy_total += sum(e - s for s, e in busy)
+        here: Dict[str, int] = collections.Counter()
         for name, s, e in dev.modules:
             # a program counts when it starts inside the window
             if lo <= s < hi:
                 prog_s[name] += (e - s) * 1e-9
-                prog_n[name] += 1
+                here[name] += 1
+        for name, k in here.items():
+            prog_n[name] = max(prog_n[name], k)
         mods = sorted((s, e, name) for name, s, e in dev.modules)
         starts = [m[0] for m in mods]
         for name, s, e in dev.ops:

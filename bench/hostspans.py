@@ -183,28 +183,28 @@ def drive_cell(spec: dict, seed: int, seconds: float, logdir: str,
     import openloop
     import run
     import system
-    from weights import make_params
     cfg_file, mix = spec["cfg"], spec["mix"]
-    cfg = system.model_config(cfg_file)
+    sut = system.System(cfg_file, spec["cell"]["chips"])
     fleet = system.fleet_config(cfg_file, generator.ring_window(mix))
-    params = jax.block_until_ready(
-        make_params(system.param_layout(cfg), seed))
-    system.warm_up(cfg, params, fleet, mix["prompt_buckets"],
-                   cfg_file["vocab_size"])
-    eng = system.make_engine(cfg, params, fleet)
-    arrivals = generator.schedule(mix, seconds, seed, cfg_file["vocab_size"])
-    tracer = None
-    if trace:
-        lo, length = mix["trace_window_s"]
-        tracer = run.Tracer(logdir, lo, min(lo + length, seconds))
-    try:
-        res = openloop.drive(eng, arrivals, seconds, mix["drain_s"],
-                             system.make_request,
-                             on_tick=tracer.on_tick if tracer else None,
-                             spans=trace)
-    finally:
-        if tracer is not None:
-            tracer.close()
+    with sut.serving():
+        params = jax.block_until_ready(sut.make_params(seed))
+        sut.warm_up(params, fleet, mix["prompt_buckets"],
+                    cfg_file["vocab_size"])
+        eng = sut.make_engine(params, fleet)
+        arrivals = generator.schedule(mix, seconds, seed,
+                                      cfg_file["vocab_size"])
+        tracer = None
+        if trace:
+            lo, length = mix["trace_window_s"]
+            tracer = run.Tracer(logdir, lo, min(lo + length, seconds))
+        try:
+            res = openloop.drive(eng, arrivals, seconds, mix["drain_s"],
+                                 system.make_request,
+                                 on_tick=tracer.on_tick if tracer else None,
+                                 spans=trace)
+        finally:
+            if tracer is not None:
+                tracer.close()
     return res, tracer
 
 

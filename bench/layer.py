@@ -4,6 +4,19 @@ Each reader is a module with ``read(ctx: LayerContext) -> float | None``.
 It returns None where it finds nothing to read, and the harness then
 leaves the metric out of the result line.  A share of a roofline or of a
 peak is never returned as 0 in place of nothing.
+
+On a cell of several chips the numbers are put together so:
+
+- work counts (``ctx.shape``: FLOPs and bytes) are of the whole model;
+- device time (``trace.program_s``, ``trace.busy_s``'s sum) is summed over
+  the cell's chips;
+- a program's calls (``trace.program_calls``) are its launches: one per
+  execution across the chips, however many planes show it.
+
+A roofline share, least time of the whole model's work at one chip's
+peak over device time summed over the chips, is then the share of each
+chip's own peak; ``serve_mfu`` divides by window x chips x peak.  On one
+chip every number is what it is without this rule.
 """
 from __future__ import annotations
 
@@ -12,7 +25,7 @@ import os
 from dataclasses import dataclass
 from typing import List, Optional
 
-import counts
+from counts import Work
 from openloop import DriveResult, TickRecord
 from devtrace import Reduced
 
@@ -23,7 +36,7 @@ DECODE_PROGRAM = "jit_decode_step"
 @dataclass
 class LayerContext:
     drive: DriveResult
-    shape: counts.Shape
+    shape: object                     # the architecture's ``Shape``
     peak_flops: float
     peak_bw: float
     chips: int
@@ -39,18 +52,18 @@ class LayerContext:
             return []
         return self.drive.ticks[self.traced_ticks]
 
-    def prefill_work(self) -> counts.Work:
-        w = counts.Work()
+    def prefill_work(self) -> Work:
+        w = Work()
         for t in self.traced():
             for rows, plen in t.prefill:
-                w.add(counts.prefill_call(self.shape, rows, plen))
+                w.add(self.shape.prefill_call(rows, plen))
         return w
 
-    def decode_work(self) -> counts.Work:
+    def decode_work(self) -> Work:
         """Per-row decode work of the traced ticks plus one weight read per
-        decode call the trace shows."""
+        decode launch the trace shows."""
         ctx = [c for t in self.traced() for c in t.decode_ctx]
-        w = counts.decode_rows(self.shape, ctx)
+        w = self.shape.decode_rows(ctx)
         w.calls = self.trace.program_calls.get(DECODE_PROGRAM, 0) \
             if self.trace else 0
         w.bytes += w.calls * self.shape.weight_bytes_per_call

@@ -1,4 +1,6 @@
-"""Decode step: mean device time of one decode call in the trace, ms."""
+"""Decode step: mean device time of one decode call in the trace, ms.  On
+a cell of several chips a call's device time is summed over its chips and
+each launch counts once (``layer.py``)."""
 from layer import DECODE_PROGRAM
 
 

@@ -1,7 +1,6 @@
 """Prefill: the least time the prefill calls in the trace need (from
-``counts.prefill_call``: the larger of FLOPs over peak and bytes over
+the shape's ``prefill_call``: the larger of FLOPs over peak and bytes over
 bandwidth, call by call) over their device time, in %."""
-import counts
 from layer import PREFILL_PROGRAM
 
 
@@ -14,7 +13,7 @@ def read(ctx):
     least = 0.0
     for t in ctx.traced():
         for rows, plen in t.prefill:
-            least += counts.prefill_call(ctx.shape, rows, plen).least_seconds(
+            least += ctx.shape.prefill_call(rows, plen).least_seconds(
                 ctx.peak_flops, ctx.peak_bw)
     if least <= 0:
         return None

@@ -14,9 +14,12 @@ windowed where the configuration has a ``sliding_window``; output
 projection; residual; RMSNorm; SwiGLU or tanh-approximated GELU MLP;
 residual.  Then the final RMSNorm and the output head.
 
-It runs one layer at a time on a block of rows, so only one layer's
-float32 copy lives on the device beside the served weights, and reduces
-the logits over the vocabulary in chunks to what the comparison needs.
+It runs one layer at a time on a block of rows, on one device: each
+layer's weights are gathered from the served tree (from their shards,
+on a cell of several chips) onto that device, so only one layer's
+float32 copy lives there beside the served weights, and the program's
+sharding never runs.  It reduces the logits over the vocabulary in chunks
+to what the comparison needs.
 
 ``quant`` puts a lower precision in place of float32 for every matrix
 (the embedding and the output head included), as the control of the
@@ -34,6 +37,24 @@ import jax.numpy as jnp
 import numpy as np
 
 NEG = -1e30
+
+
+def on_one_device(tree):
+    """``tree`` on the first device, gathered from its shards if it has
+    any (an array already there is not copied)."""
+    return jax.device_put(tree, jax.devices()[0])
+
+
+@jax.jit
+def _take_layer(stack, li):
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False),
+        stack)
+
+
+def layer_on_one_device(stack, li: int):
+    """Layer ``li`` of a tree of stacked layers, on the first device."""
+    return on_one_device(_take_layer(stack, li))
 
 
 def _quant(w: jnp.ndarray, quant: Optional[str]) -> jnp.ndarray:
@@ -112,11 +133,9 @@ class Reference:
         out = jax.lax.map(block, jnp.arange(S // qb))     # (nq, B, qb, H, hd)
         return out.transpose(1, 0, 2, 3, 4).reshape(B, S, H * hd)
 
-    def _layer(self, x, reps, li):
-        """x: (B, S, d) float32; ``reps`` the stacked layer weights."""
+    def _layer(self, x, w):
+        """x: (B, S, d) float32; ``w`` one layer's weights."""
         c, qn = self.c, self.quant
-        at = lambda a: a[li]
-        w = jax.tree.map(at, reps)
         m = w["mixer"]
         B, S, _ = x.shape
         H, KV, hd = (c["num_attention_heads"], c["num_key_value_heads"],
@@ -177,20 +196,21 @@ class Reference:
     def hidden(self, params, tokens: np.ndarray) -> jnp.ndarray:
         """float32 hidden states before the final norm, (B, S, d)."""
         with jax.default_matmul_precision("highest"):
-            rows = params["embed"]["table"][jnp.asarray(tokens)]
+            rows = on_one_device(
+                params["embed"]["table"][jnp.asarray(tokens)])
             # an embedding row is one output channel: round it on its own
             x = _quant(rows[..., None], self.quant)[..., 0] if self.quant \
                 else rows.astype(jnp.float32)
             reps = params["reps"][0]
             for li in range(self.c["num_hidden_layers"]):
-                x = self.layer(x, reps, li)
+                x = self.layer(x, layer_on_one_device(reps, li))
             return x
 
     def reduce(self, params, x, pos: np.ndarray, tgt: np.ndarray):
         with jax.default_matmul_precision("highest"):
-            return self.final(x, params["final_norm"]["scale"],
-                              params["embed"]["out"], jnp.asarray(pos),
-                              jnp.asarray(tgt))
+            return self.final(x, on_one_device(params["final_norm"]["scale"]),
+                              on_one_device(params["embed"]["out"]),
+                              jnp.asarray(pos), jnp.asarray(tgt))
 
 
 Q_BLOCK = 512      # query rows per attention block

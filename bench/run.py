@@ -11,13 +11,17 @@ is.  A run:
    JAX finds no TPU, fewer chips than the cell asks for, or a device kind
    missing from ``bench/peaks.json``;
 2. enables the program's persistent compilation cache;
-3. makes the weights on the device from ``--seed``;
+3. makes the weights on the device from ``--seed`` (``system.System``:
+   on a cell of several chips, straight into their shards on a
+   ``(data=1, model=chips)`` mesh, served with the program's sharding);
 4. warms up every program shape the cell's traffic can reach;
 5. serves the seed's schedule through ``FleetEngine`` for ``--seconds``,
    then drains every request sent (``openloop.py``); with ``--trace 1`` the
    profiler records a part of the window, named in the traffic file;
-6. reads the peak device memory, frees the serving state, and compares a
-   sample of the served tokens with the float32 reference (``check.py``);
+6. reads the peak device memory (of the fullest chip), frees the serving
+   state, and compares a sample of the served tokens with the float32
+   reference of the configuration's architecture (``check.py``,
+   ``bench/archs``);
 7. prints the compared numbers with their limits on standard error, and
    one JSON line on standard output: the cell's end-to-end metrics with
    ``--trace 0``, its per-layer metrics (``bench/metrics/<name>.py``) with
@@ -178,27 +182,26 @@ def serve_cell(spec: dict, seed: int, seconds: float, trace: bool,
     import system
     from check import draw_sample, logit_gaps
     from layer import LayerContext, load_reader
-    from reference import Reference
 
     cell, cfg_file, mix = spec["cell"], spec["cfg"], spec["mix"]
-    cfg = system.model_config(cfg_file)
+    sut = system.System(cfg_file, cell["chips"])
     window = generator.ring_window(mix)
     fleet = system.fleet_config(cfg_file, window)
-    with CompileCounter() as compiles:
+    with CompileCounter() as compiles, sut.serving():
         t = time.perf_counter()
-        from weights import make_params
-        params = jax.block_until_ready(
-            make_params(system.param_layout(cfg), seed))
+        params = jax.block_until_ready(sut.make_params(seed))
         log(f"weights: {sum(x.size for x in jax.tree.leaves(params)) / 1e9:.3f}"
-            f" B parameters in {time.perf_counter() - t:.2f} s")
+            f" B parameters in {time.perf_counter() - t:.2f} s; "
+            f"{weight_bytes_per_chip(params, devs[:cell['chips']])} bytes "
+            f"per chip")
         t = time.perf_counter()
         if warm:
-            n = system.warm_up(cfg, params, fleet, mix["prompt_buckets"],
-                               cfg_file["vocab_size"])
+            n = sut.warm_up(params, fleet, mix["prompt_buckets"],
+                            cfg_file["vocab_size"])
             log(f"warm-up: {n} in {time.perf_counter() - t:.2f} s; "
                 f"{compiles.count} executables built ({compiles.cache_hits} "
                 f"from the cache) in {compiles.seconds:.2f} s")
-        eng = system.make_engine(cfg, params, fleet)
+        eng = sut.make_engine(params, fleet)
         arrivals = generator.schedule(mix, seconds, seed,
                                       cfg_file["vocab_size"])
         log(f"schedule: {generator.describe(arrivals)}")
@@ -234,13 +237,14 @@ def serve_cell(spec: dict, seed: int, seconds: float, trace: bool,
     # the harness's own cost per tick: run()'s finalize and summary,
     # timed on a call that ticks nothing
     t = time.perf_counter()
-    for _ in range(20):
-        eng.run(max_ticks=eng.wall)
+    with sut.serving():
+        for _ in range(20):
+            eng.run(max_ticks=eng.wall)
     harness_ms = (time.perf_counter() - t) / 20 * 1000
     log(f"harness: run() finalize + telemetry summary {harness_ms:.3f} ms "
         f"per call ({len(eng.requests)} requests held)")
 
-    shape = counts.Shape.from_config(cfg_file)
+    shape = sut.arch.Shape.from_config(cfg_file)
     metrics = {}
     breakdown = None
     if trace:
@@ -286,6 +290,7 @@ def serve_cell(spec: dict, seed: int, seconds: float, trace: bool,
     t = time.perf_counter()
     check = cfg_file["check"]
     sample = draw_sample(res.tracked, mix["check_requests"], seed)
+    Reference = sut.arch.Reference
     gaps = logit_gaps(Reference(cfg_file), params, sample, window,
                       generator.max_output(mix),
                       rows=max(1, check["reference_tokens"] // window),
@@ -321,6 +326,16 @@ def serve_cell(spec: dict, seed: int, seconds: float, trace: bool,
         line["reconfigs"] = res.reconfigs
     line["compared"] = compared
     return line
+
+
+def weight_bytes_per_chip(params, devs) -> int:
+    """Bytes of the weight tree on the fullest of ``devs``."""
+    import jax
+    held = dict.fromkeys(devs, 0)
+    for leaf in jax.tree.leaves(params):
+        for shard in leaf.addressable_shards:
+            held[shard.device] += shard.data.nbytes
+    return max(held.values())
 
 
 def judge(check: dict, sample, widest: float, failed) -> tuple:

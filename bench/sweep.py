@@ -26,39 +26,23 @@ import sys
 import run
 
 
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--rates", required=True)
-    ap.add_argument("--seconds", type=float, default=51.0)
-    ap.add_argument("--seed", type=int, default=1)
-    ap.add_argument("--drain", type=float, default=60.0)
-    ap.add_argument("--write-rate", action="store_true")
-    args = ap.parse_args(argv)
-    spec = run.load_cell(args.workload)
-    devs, _ = run.require_device(spec["cell"]["chips"])
+def _serve_rates(sut, fleet, spec: dict, args) -> list:
+    """Serve the cell at each rate of ``--rates``, one engine each, after
+    one warm-up; returns a row per rate."""
     import jax
 
     import generator
     import openloop
     import system
-    from repro.compile_cache import enable_compile_cache
-    from weights import make_params
-    enable_compile_cache()
     cfg_file, mix0 = spec["cfg"], spec["mix"]
-    cfg = system.model_config(cfg_file)
-    fleet = system.fleet_config(cfg_file, generator.ring_window(mix0))
-    params = jax.block_until_ready(
-        make_params(system.param_layout(cfg), args.seed))
-    system.warm_up(cfg, params, fleet, mix0["prompt_buckets"],
-                   cfg_file["vocab_size"])
-    out_dir = os.path.join(run.ROOT, "chiprun_out", "sweep")
-    os.makedirs(out_dir, exist_ok=True)
+    params = jax.block_until_ready(sut.make_params(args.seed))
+    sut.warm_up(params, fleet, mix0["prompt_buckets"],
+                cfg_file["vocab_size"])
     rows = []
     for rate in (float(r) for r in args.rates.split(",")):
         mix = copy.deepcopy(mix0)
         mix["arrivals"]["rate_rps"] = rate
-        eng = system.make_engine(cfg, params, fleet)
+        eng = sut.make_engine(params, fleet)
         arrivals = generator.schedule(mix, args.seconds, args.seed,
                                       cfg_file["vocab_size"])
         res = openloop.drive(eng, arrivals, args.seconds, args.drain,
@@ -77,6 +61,31 @@ def main(argv=None) -> int:
         rows.append(row)
         print(json.dumps(row), flush=True)
         del eng
+    return rows
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--rates", required=True)
+    ap.add_argument("--seconds", type=float, default=51.0)
+    ap.add_argument("--seed", type=int, default=1)
+    ap.add_argument("--drain", type=float, default=60.0)
+    ap.add_argument("--write-rate", action="store_true")
+    args = ap.parse_args(argv)
+    spec = run.load_cell(args.workload)
+    devs, _ = run.require_device(spec["cell"]["chips"])
+    import generator
+    import system
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    out_dir = os.path.join(run.ROOT, "chiprun_out", "sweep")
+    os.makedirs(out_dir, exist_ok=True)
+    sut = system.System(spec["cfg"], spec["cell"]["chips"])
+    fleet = system.fleet_config(spec["cfg"], generator.ring_window(
+        spec["mix"]))
+    with sut.serving():
+        rows = _serve_rates(sut, fleet, spec, args)
     knee = None
     for r in sorted(rows, key=lambda r: r["rate_rps"]):
         if r["grows"]:

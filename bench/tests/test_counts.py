@@ -44,13 +44,13 @@ def test_parameter_counts():
 def test_decode_call(name, layer, head, wbytes, kvpos, H, d, V):
     # a batch of three rows, one of them done: only the two live rows,
     # at 600 and 130 attended positions, count
-    w = counts.decode_call(shape(name), [600, 130])
+    w = shape(name).decode_call([600, 130])
     attn = 8 * 4 * H * 128 * (600 + 130)
     assert w.flops == 2 * (2 * 8 * layer + 2 * head) + attn
     assert w.bytes == (wbytes + kvpos * (599 + 129) + 2 * kvpos
                        + 2 * (2 * d + 2 * V))
     assert w.calls == 1
-    assert counts.decode_call(shape(name), []).bytes == 0
+    assert shape(name).decode_call([]).bytes == 0
 
 
 @pytest.mark.parametrize("name,layer,head,wbytes,kvpos,H,d,V", [
@@ -62,7 +62,7 @@ def test_decode_call(name, layer, head, wbytes, kvpos, H, d, V):
 def test_prefill_call(name, layer, head, wbytes, kvpos, H, d, V):
     # 3 prompts of 128 tokens: causal attention over 128*129/2 pairs,
     # logits of the last position only
-    w = counts.prefill_call(shape(name), 3, 128)
+    w = shape(name).prefill_call(3, 128)
     assert w.flops == 3 * (128 * 2 * 8 * layer
                            + 8 * 4 * H * 128 * (128 * 129 // 2) + 2 * head)
     assert w.bytes == wbytes + 3 * 128 * kvpos + 3 * 128 * d * 2 + 3 * V * 2
@@ -70,8 +70,8 @@ def test_prefill_call(name, layer, head, wbytes, kvpos, H, d, V):
 
 def test_decode_rows_leave_out_the_weights():
     s = shape("qwen3-14b")
-    full = counts.decode_call(s, [10, 20])
-    rows = counts.decode_rows(s, [10, 20])
+    full = s.decode_call([10, 20])
+    rows = s.decode_rows([10, 20])
     assert rows.flops == full.flops
     assert rows.bytes == full.bytes - s.weight_bytes_per_call
     assert rows.calls == 0
@@ -80,8 +80,8 @@ def test_decode_rows_leave_out_the_weights():
 def test_least_time_and_bound():
     s = shape("qwen3-14b")
     peak_f, peak_b = 197e12, 819e9
-    small = counts.prefill_call(s, 1, 128)
-    big = counts.prefill_call(s, 16, 512)
+    small = s.prefill_call(1, 128)
+    big = s.prefill_call(16, 512)
     assert small.least_seconds(peak_f, peak_b) == small.bytes / peak_b
     assert big.least_seconds(peak_f, peak_b) == big.flops / peak_f
     for name, ctx in (("qwen3-14b", 1024), ("starcoder2-15b", 2176)):

@@ -1,16 +1,20 @@
 """A run with the timed path broken underneath must come out not
 correct.  Each fault is planted in the program's jitted decode or
-prefill (the engine looks both up when it is built), and the rest of the
-run is the benchmark's own: driver, drain, sample and reference.  The
-device check is skipped by calling the test-only entry
-``run.serve_cell``.
+prefill (the engine looks both up when it is called), or for half of the
+batch in the slot pool's decode, which knows the rows that hold live
+requests; the rest of the run is the benchmark's own: driver, drain,
+sample and reference.  The device check is skipped by calling the
+test-only entry ``run.serve_cell``.
 
 Faults a served cell can have: a token altered where it is produced (in
 prefill, in decode), half of the batch given other rows' results, and a
-decode step that returns its state unchanged.  A one-chip cell has no
-exchange between chips to leave out.
+decode step that returns its state unchanged (a copy of the state it
+was given, which the call donates).  A one-chip cell has no exchange
+between chips to leave out.
 """
 import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 import run
@@ -21,6 +25,7 @@ from repro.serve import engine
 SEED = 2 ** 31 + 404
 ORIG_DECODE = engine.jit_decode
 ORIG_PREFILL = engine.jit_prefill
+ORIG_POOL_DECODE = engine.SlotPool.decode
 
 
 def _flip_first_row(logits):
@@ -38,27 +43,42 @@ def prefill_token_altered(params, batch, cfg, rt, window):
     return _flip_first_row(logits), st
 
 
-def decode_half_batch(params, state, tokens, cfg, rt):
-    logits, st = ORIG_DECODE(params, state, tokens, cfg=cfg, rt=rt)
-    B = logits.shape[0]
-    if B < 2:
-        return _flip_first_row(logits), st
-    h = B // 2
-    # the second half of the rows is left out: it gets the first half's
-    # results
-    return logits.at[h:2 * h].set(logits[:h]), st
+def decode_half_batch(pool, now, **ids):
+    """The pool's decode with half of the rows that hold live requests
+    left out, rounded up: each gets another row's results, the first
+    half's in order, then those of rows that hold none.  At the tiny
+    mix's load most decodes hold one live request, which is then the
+    half left out."""
+    live = sorted(row for _, part in pool._marked
+                  for row, r in zip(part.rows, part.requests) if not r.done)
+    h = len(live) // 2
+    out = live[h:]
+    src = (live[:h] + [r for r in range(pool.rows)
+                       if r not in live])[:len(out)]
+
+    def decode(params, state, tokens, cfg, rt):
+        logits, st = ORIG_DECODE(params, state, tokens, cfg=cfg, rt=rt)
+        return logits.at[np.asarray(out)].set(logits[np.asarray(src)]), st
+
+    engine.jit_decode = decode if out else ORIG_DECODE
+    try:
+        return ORIG_POOL_DECODE(pool, now, **ids)
+    finally:
+        engine.jit_decode = ORIG_DECODE
 
 
 def decode_state_unchanged(params, state, tokens, cfg, rt):
+    # a copy, since the call donates (deletes) the state it is given
+    kept = jax.tree.map(jnp.copy, state)
     logits, _ = ORIG_DECODE(params, state, tokens, cfg=cfg, rt=rt)
-    return logits, state
+    return logits, kept
 
 
 FAULTS = {
-    "decode_token_altered": ("jit_decode", decode_token_altered),
-    "prefill_token_altered": ("jit_prefill", prefill_token_altered),
-    "decode_half_batch": ("jit_decode", decode_half_batch),
-    "decode_state_unchanged": ("jit_decode", decode_state_unchanged),
+    "decode_token_altered": (engine, "jit_decode", decode_token_altered),
+    "prefill_token_altered": (engine, "jit_prefill", prefill_token_altered),
+    "decode_half_batch": (engine.SlotPool, "decode", decode_half_batch),
+    "decode_state_unchanged": (engine, "jit_decode", decode_state_unchanged),
 }
 
 
@@ -73,8 +93,8 @@ def test_sound_run_is_correct(tmp_path):
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
 def test_fault_is_not_correct(fault, monkeypatch, tmp_path):
-    name, fn = FAULTS[fault]
-    monkeypatch.setattr(engine, name, fn)
+    owner, name, fn = FAULTS[fault]
+    monkeypatch.setattr(owner, name, fn)
     line = _run(tmp_path)
     assert line["correct"] is False, line["compared"]
     gap = line["compared"]["max_logit_gap"]

@@ -11,7 +11,6 @@ import run
 import system
 from conftest import TINY_MIX, tiny, tiny_spec
 from layer import LayerContext, load_reader
-from weights import make_params
 
 BIG_SEED = 2 ** 31 + 77
 
@@ -19,15 +18,14 @@ BIG_SEED = 2 ** 31 + 77
 @pytest.fixture(scope="module")
 def model():
     cfg_file = tiny()
-    cfg = system.model_config(cfg_file)
-    params = make_params(system.param_layout(cfg), BIG_SEED)
-    return cfg_file, cfg, params
+    sut = system.System(cfg_file)
+    return cfg_file, sut, sut.make_params(BIG_SEED)
 
 
 def _drive(model, mix, seconds, drain_s, seed):
-    cfg_file, cfg, params = model
+    cfg_file, sut, params = model
     fleet = system.fleet_config(cfg_file, generator.ring_window(mix))
-    eng = system.make_engine(cfg, params, fleet)
+    eng = sut.make_engine(params, fleet)
     arrivals = generator.schedule(mix, seconds, seed, cfg_file["vocab_size"])
     return arrivals, openloop.drive(eng, arrivals, seconds, drain_s,
                                     system.make_request)
@@ -105,16 +103,16 @@ def test_warm_up_leaves_nothing_to_compile():
     to this process."""
     cfg_file = tiny()
     cfg_file["num_hidden_layers"] = 3
-    cfg = system.model_config(cfg_file)
-    params = make_params(system.param_layout(cfg), BIG_SEED)
+    sut = system.System(cfg_file)
+    params = sut.make_params(BIG_SEED)
     fleet = system.fleet_config(cfg_file, generator.ring_window(TINY_MIX))
-    n = system.warm_up(cfg, params, fleet, TINY_MIX["prompt_buckets"],
-                       cfg_file["vocab_size"])
+    n = sut.warm_up(params, fleet, TINY_MIX["prompt_buckets"],
+                    cfg_file["vocab_size"])
     cap = fleet.capacity
     assert n["waves"] == 2 * cap
     assert n["take"] == cap * (cap + 1) // 2 - 1
     assert n["concat"] == cap * (cap - 1) // 2
-    eng = system.make_engine(cfg, params, fleet)
+    eng = sut.make_engine(params, fleet)
     # a load at which waves hold both buckets and groups split and fuse
     mix = dict(TINY_MIX, arrivals=dict(TINY_MIX["arrivals"], rate_rps=40.0))
     arrivals = generator.schedule(mix, 3.0, 11, cfg_file["vocab_size"])

@@ -7,8 +7,8 @@ import pytest
 
 import devtrace as tr
 
-DATA = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
-    __file__))), "testdata", "v5e_two_programs.xplane.pb")
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+DATA = os.path.join(BENCH, "testdata", "v5e_two_programs.xplane.pb")
 
 
 def _events():
@@ -96,3 +96,44 @@ def test_align_moves_early_device_events_into_the_window():
     al = tr.align(ev).devices["/device:TPU:0"]
     assert min(s for _, s, _ in al.ops + al.modules) == 0
     assert [o[1] for o in al.ops] == [0, 15, 50]
+
+
+def _decode_trace(planes: int) -> tr.Events:
+    """Two decode launches and a prefill in a 0..1000 ns window.  On one
+    plane each takes its whole time; split over ``planes`` chips, each
+    plane shows every launch at its start, taking its share of the time."""
+    one = [("jit_decode_step", 100, 500), ("jit_prefill", 520, 600),
+           ("jit_decode_step", 600, 1000)]
+    devices = {}
+    for p in range(planes):
+        mods = [(n, s, s + (e - s) / planes) for n, s, e in one]
+        devices[f"/device:TPU:{p}"] = tr.DeviceEvents(
+            ops=[("fusion.1", s, e) for _, s, e in mods], modules=mods)
+    return tr.Events(devices=devices, host=[("traced", 0, 1000),
+                                            ("tick", 0, 1000)])
+
+
+def test_four_planes_count_launches_and_keep_the_decode_readings():
+    import counts
+    from conftest import load_config
+    from layer import LayerContext, load_reader
+    from openloop import DriveResult, TickRecord
+    shape = counts.Shape.from_config(load_config("qwen3-14b"))
+    ticks = [TickRecord(t=0.0, queue=0, prefill=[], decode_ctx=[300, 40]),
+             TickRecord(t=0.0, queue=0, prefill=[], decode_ctx=[301, 41])]
+    drive = DriveResult(tracked=[], ticks=ticks, window_s=1.0, end_s=1.0,
+                        stats_at={}, reconfigs=0)
+    read = {}
+    for planes in (1, 4):
+        red = tr.reduce(_decode_trace(planes))
+        assert red.devices == planes
+        assert red.program_calls == {"jit_decode_step": 2, "jit_prefill": 1}
+        assert red.program_s["jit_decode_step"] == pytest.approx(800e-9)
+        ctx = LayerContext(drive=drive, shape=shape, peak_flops=197e12,
+                           peak_bw=819e9, chips=planes, window_compiles=0,
+                           trace=red, traced_ticks=slice(0, 2))
+        read[planes] = {m: load_reader(BENCH, m)(ctx)
+                        for m in ("decode_roofline", "decode_call_ms")}
+    assert read[4]["decode_call_ms"] == pytest.approx(400e-6)
+    for m in read[1]:
+        assert read[4][m] == pytest.approx(read[1][m], rel=1e-12)
